@@ -95,7 +95,8 @@ def _child(mode: str) -> None:
 
 
 def _run_child(mode: str) -> dict:
-    env = dict(os.environ)
+    # the child measures host RSS and needs no chip; the parent may hold it
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = os.path.abspath(src) + os.pathsep \
         + env.get("PYTHONPATH", "")

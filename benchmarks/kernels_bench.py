@@ -1,6 +1,6 @@
-"""Pallas kernel microbenchmarks (interpret mode on CPU — correctness-path
-timing; on a real TPU re-run with REPRO_PALLAS_INTERPRET=0) plus the jnp
-reference path, which is what the compiled search uses on CPU."""
+"""Pallas kernel microbenchmarks next to the jnp reference path. On a TPU
+the kernels run compiled; on any other backend they run in interpret mode,
+so those timings measure the interpreter, not a kernel."""
 from __future__ import annotations
 
 import jax.numpy as jnp
@@ -41,7 +41,8 @@ def main(out=print) -> None:
     for name, kern, ref in pairs:
         _, us_k = timed(blocked(kern))
         _, us_r = timed(blocked(ref))
-        out(f"kernels/{name}_interp,{us_k:.1f},ref_jnp_us={us_r:.1f}")
+        mode = "interp" if ops.interpret_mode() else "mosaic"
+        out(f"kernels/{name}_{mode},{us_k:.1f},ref_jnp_us={us_r:.1f}")
 
 
 if __name__ == "__main__":

@@ -8,6 +8,9 @@ for the paper claim it validates).
 ``--only`` takes EXACT module names; append ``*`` for explicit prefix
 matching (``--only 'fig1*'`` runs fig11..fig17 — a bare ``fig1`` used to,
 silently). ``--list`` prints the registered modules and exits.
+
+A module that raises prints a ``<module>/FAILED`` line and the run goes on
+to the next one; the script then exits 1.
 """
 from __future__ import annotations
 
@@ -53,7 +56,7 @@ def selected(modname: str, only: list[str]) -> bool:
     return False
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="",
                     help="comma-separated module names (exact; 'prefix*' "
@@ -66,7 +69,7 @@ def main() -> None:
     if args.list:
         for modname in MODULES + SUBPROCESS_MODULES:
             print(modname)
-        return
+        return 0
 
     unknown = [o for o in only
                if not any(selected(m, [o]) for m in MODULES + SUBPROCESS_MODULES)]
@@ -75,6 +78,10 @@ def main() -> None:
               f"(see --list; use 'prefix*' for prefix matching)",
               file=sys.stderr)
 
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
+    failed = []
     print("name,us_per_call,derived")
     for modname in MODULES:
         if only and not selected(modname, only):
@@ -85,16 +92,18 @@ def main() -> None:
             mod.main(out=print)
             print(f"# {modname} done in {time.perf_counter()-t0:.1f}s", file=sys.stderr)
         except Exception:
+            failed.append(modname)
             print(f"{modname}/FAILED,0.0,{traceback.format_exc().splitlines()[-1]}")
             traceback.print_exc(file=sys.stderr)
 
-    # distributed-search dry-run needs 512 host devices -> own process
+    # distributed-search dry-run needs 512 host devices -> own process. It
+    # is a CPU compile by design, and this process may hold the chip.
     if not only or selected("proxima_dryrun", only):
         import os
         import subprocess
 
         t0 = time.perf_counter()
-        env = dict(os.environ)
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         r = subprocess.run(
             [sys.executable, "-m", "benchmarks.proxima_dryrun"],
             capture_output=True, text=True, timeout=900, env=env,
@@ -103,12 +112,14 @@ def main() -> None:
             if line.startswith("proxima-dist"):
                 print(line)
         if r.returncode != 0:
+            failed.append("proxima_dryrun")
             print(f"proxima_dryrun/FAILED,0.0,rc={r.returncode}")
             print(r.stderr[-1500:], file=sys.stderr)
         else:
             print(f"# proxima_dryrun done in {time.perf_counter()-t0:.1f}s",
                   file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

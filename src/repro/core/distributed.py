@@ -34,7 +34,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import SearchConfig, upgrade_config
@@ -78,7 +77,8 @@ def shard_corpus(
     hot_count: int,
     num_shards: int,
 ) -> ShardedCorpus:
-    """Round-robin partition: vertex i -> (shard i % P, local row i // P)."""
+    """Round-robin partition: vertex i -> (shard i % P, local row i // P).
+    Returns host (NumPy) arrays; :func:`place_corpus` puts them on a mesh."""
     n = adjacency.shape[0]
     pad = (-n) % num_shards
     if pad:
@@ -89,17 +89,39 @@ def shard_corpus(
     order = np.arange(npad).reshape(npad // num_shards, num_shards).T  # (P, N/P)
     h = max(int(hot_count), 1)
     return ShardedCorpus(
-        adjacency=jnp.asarray(adjacency[order]),
-        codes=jnp.asarray(codes[order]),
-        base=jnp.asarray(base[order]),
-        centroids=jnp.asarray(centroids),
-        hot_adjacency=jnp.asarray(adjacency[:h]),
-        hot_codes=jnp.asarray(codes[:h]),
-        hot_base=jnp.asarray(base[:h]),
-        entry_point=jnp.int32(entry_point),
-        hot_count=jnp.int32(hot_count),
+        adjacency=np.asarray(adjacency)[order],
+        codes=np.asarray(codes)[order],
+        base=np.asarray(base)[order],
+        centroids=np.asarray(centroids),
+        hot_adjacency=np.asarray(adjacency[:h]),
+        hot_codes=np.asarray(codes[:h]),
+        hot_base=np.asarray(base[:h]),
+        entry_point=np.int32(entry_point),
+        hot_count=np.int32(hot_count),
         num_vertices=n,
         num_shards=num_shards,
+    )
+
+
+SHARDED_FIELDS = ("adjacency", "codes", "base")
+REPLICATED_FIELDS = ("centroids", "hot_adjacency", "hot_codes", "hot_base",
+                     "entry_point", "hot_count")
+
+
+def place_corpus(corpus: ShardedCorpus, mesh: Mesh,
+                 data_axis: str = "data") -> ShardedCorpus:
+    """Put each shard of the (P, N/P, ·) arrays on its own device along
+    ``data_axis`` (replicated over the mesh's other axes) and replicate the
+    codebook, hot-node replicas and scalars on every device. Each shard
+    goes straight from the host to its device; no device holds the whole
+    corpus on the way."""
+    sharded = NamedSharding(mesh, P(data_axis))
+    replicated = NamedSharding(mesh, P())
+    return corpus._replace(
+        **{f: jax.device_put(getattr(corpus, f), sharded)
+           for f in SHARDED_FIELDS},
+        **{f: jax.device_put(getattr(corpus, f), replicated)
+           for f in REPLICATED_FIELDS},
     )
 
 
@@ -342,7 +364,7 @@ def distributed_search_kernel(
     pspec_sharded = P(data_axis, None, None)
     pspec_rep = P()
     q_spec = P(queue_axis, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         engine,
         mesh=mesh,
         in_specs=(
@@ -352,7 +374,7 @@ def distributed_search_kernel(
             q_spec,                                       # queries
         ),
         out_specs=(q_spec, q_spec),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(
         corpus.adjacency, corpus.codes, corpus.base,

@@ -43,6 +43,12 @@ class PQCodebook:
         return self.num_subvectors * int(np.ceil(np.log2(self.num_centroids)))
 
 
+# f32 matmuls at full precision: the TPU's default rounds f32 operands to
+# bf16, which would give the chip other centroids, codes and ADTs than the
+# CPU (and the NumPy oracle) compute for the same data
+_EXACT = jax.lax.Precision.HIGHEST
+
+
 def _split(x: jnp.ndarray, m: int) -> jnp.ndarray:
     """(..., D) -> (..., M, dsub)."""
     return x.reshape(*x.shape[:-1], m, x.shape[-1] // m)
@@ -59,13 +65,13 @@ def _kmeans_one(sub: jnp.ndarray, init: jnp.ndarray, iters: int) -> jnp.ndarray:
     def step(cent, _):
         d = (
             (sub * sub).sum(-1)[:, None]
-            - 2.0 * sub @ cent.T
+            - 2.0 * jnp.matmul(sub, cent.T, precision=_EXACT)
             + (cent * cent).sum(-1)[None, :]
         )
         assign = jnp.argmin(d, axis=1)
         onehot = jax.nn.one_hot(assign, cent.shape[0], dtype=sub.dtype)
         counts = onehot.sum(0)
-        sums = onehot.T @ sub
+        sums = jnp.matmul(onehot.T, sub, precision=_EXACT)
         new = jnp.where(counts[:, None] > 0, sums / jnp.maximum(counts, 1)[:, None], cent)
         return new, None
 
@@ -104,7 +110,8 @@ def encode(data: jnp.ndarray, centroids: jnp.ndarray) -> jnp.ndarray:
     subs = _split(data, m)                                     # (N, M, dsub)
     d = (
         (subs * subs).sum(-1)[..., None]
-        - 2.0 * jnp.einsum("nmd,mcd->nmc", subs, centroids)
+        - 2.0 * jnp.einsum("nmd,mcd->nmc", subs, centroids,
+                           precision=_EXACT)
         + (centroids * centroids).sum(-1)[None]
     )
     return jnp.argmin(d, axis=-1).astype(jnp.uint8)
@@ -123,10 +130,11 @@ def compute_adt(query: jnp.ndarray, centroids: jnp.ndarray, metric: str = "l2") 
     if metric == "l2":
         return (
             (qs * qs).sum(-1)[:, None]
-            - 2.0 * jnp.einsum("md,mcd->mc", qs, centroids)
+            - 2.0 * jnp.einsum("md,mcd->mc", qs, centroids,
+                               precision=_EXACT)
             + (centroids * centroids).sum(-1)
         )
-    return -jnp.einsum("md,mcd->mc", qs, centroids)
+    return -jnp.einsum("md,mcd->mc", qs, centroids, precision=_EXACT)
 
 
 @jax.jit

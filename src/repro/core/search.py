@@ -114,11 +114,13 @@ def l2_normalize(x, xp=jnp):
 def _exact_dist(q, x, metric: str):
     """q (D,), x (K, D) -> (K,). Angular assumes pre-normalized inputs.
     Operator-only arithmetic: works identically on jnp (traced search) and
-    np (reference oracle) inputs — the single exact-distance path."""
+    np (reference oracle) inputs — the single exact-distance path. The
+    inner product is an elementwise sum, not a matmul, so it stays f32 on
+    the TPU, whose default matmul precision rounds f32 operands to bf16."""
     if metric == "l2":
         diff = x - q[None, :]
         return (diff * diff).sum(-1)
-    return -(x @ q)
+    return -(x * q[None, :]).sum(-1)
 
 
 def _dedup_round(neighbors: jnp.ndarray) -> jnp.ndarray:
@@ -159,18 +161,18 @@ def _merge_sort_topl_bitonic(ids, dists, acc, evaluated, n_ids, n_dists):
     from repro.kernels import ops
 
     l = ids.shape[0]
-    all_ids = jnp.concatenate([ids, n_ids])
-    all_d = jnp.concatenate([dists, n_dists])
-    all_acc = jnp.concatenate([acc, jnp.full(n_ids.shape, INF)])
-    all_ev = jnp.concatenate([evaluated, jnp.zeros(n_ids.shape, bool)])
-    total = all_d.shape[0]
-    pot = next_pow2(total)
-    keys = jnp.pad(all_d, (0, pot - total), constant_values=jnp.inf)
-    pos = jnp.pad(jnp.arange(total, dtype=jnp.int32), (0, pot - total),
-                  constant_values=0)
+    pad = next_pow2(l + n_ids.shape[0]) - l - n_ids.shape[0]
+    # the power-of-two padding is itself empty entries (id -1, +inf), so a
+    # padding slot sorted into the top L reads as empty
+    all_ids = jnp.concatenate([ids, n_ids, jnp.full((pad,), -1, jnp.int32)])
+    all_d = jnp.concatenate([dists, n_dists, jnp.full((pad,), INF)])
+    all_acc = jnp.concatenate([acc, jnp.full((n_ids.shape[0] + pad,), INF)])
+    all_ev = jnp.concatenate([evaluated,
+                              jnp.zeros((n_ids.shape[0] + pad,), bool)])
+    pos = jnp.arange(all_d.shape[0], dtype=jnp.int32)
     # NOTE: bitonic is not stable; +inf-keyed entries are interchangeable
     # (all carry id=-1), so only exact finite-key ties can reorder.
-    _, perm = ops.bitonic_sort_pairs(keys[None], pos[None])
+    _, perm = ops.bitonic_sort_pairs(all_d[None], pos[None])
     perm = perm[0, :l]
     return all_ids[perm], all_d[perm], all_acc[perm], all_ev[perm]
 

@@ -26,21 +26,11 @@ _HINT_MESH: Optional[Mesh] = None
 
 
 def abstract_mesh(axis_sizes, axis_names):
-    """Version-portable ``jax.sharding.AbstractMesh`` constructor.
-
-    jax >= 0.5 takes ``(axis_sizes, axis_names)``; jax 0.4.x takes a single
-    ``shape_tuple`` of (name, size) pairs. Only the axis-name -> size mapping
-    matters to the sharding rules, so either form works downstream.
-    """
+    """A device-free ``jax.sharding.AbstractMesh``: only the axis-name ->
+    size mapping matters to the sharding rules."""
     from jax.sharding import AbstractMesh
 
-    # try the 0.4.x single-argument form first: on newer jax it fails the
-    # signature bind (axis_names required), while the reverse order could
-    # silently misroute axis_names into 0.4.x's positional axis_types
-    try:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-    except TypeError:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 
 class activation_hints:

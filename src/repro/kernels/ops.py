@@ -1,10 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On this container (CPU) the kernels execute in ``interpret=True`` mode, which
-runs the kernel bodies in Python for correctness validation; on a real TPU
-set ``REPRO_PALLAS_INTERPRET=0`` (or pass interpret=False) to compile them to
-Mosaic. ``use_kernels()`` gates whether the search layer routes through the
-Pallas path or the pure-jnp reference path.
+Interpret mode follows the backend alone: on a TPU the kernels compile to
+Mosaic; on any other backend (the CPU test suite) they run with
+``interpret=True``, which evaluates the kernel bodies for correctness only.
+``SearchConfig.use_pallas`` gates whether the search layer routes through
+the Pallas path or the pure-jnp path.
 
 Observability (``repro.obs``): ``set_observability`` points a module-level
 hook at a registry; each wrapper then reports
@@ -22,7 +22,6 @@ zero cost when observability is off.
 """
 from __future__ import annotations
 
-import os
 import time
 
 import jax
@@ -62,15 +61,14 @@ def _instrumented(name: str, operands, fn):
     return out
 
 
-def _interpret_default() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
+def interpret_mode() -> bool:
+    """True on every backend but the TPU; off the TPU the kernels are
+    only interpreted, for correctness."""
     return jax.default_backend() != "tpu"
 
 
 def pq_adt(queries, centroids, metric="l2", interpret=None):
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     q = queries.shape[0]
     q_block = 8 if q % 8 == 0 else (4 if q % 4 == 0 else 1)
     return _instrumented(
@@ -81,7 +79,7 @@ def pq_adt(queries, centroids, metric="l2", interpret=None):
 
 
 def pq_lookup(codes, adt, interpret=None):
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     return _instrumented(
         "pq_lookup", (codes, adt),
         lambda: _pq_lookup(codes, adt, interpret=interpret),
@@ -89,7 +87,7 @@ def pq_lookup(codes, adt, interpret=None):
 
 
 def bitonic_sort_pairs(keys, vals, interpret=None):
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     return _instrumented(
         "bitonic_sort_pairs", (keys, vals),
         lambda: _bitonic(keys, vals, interpret=interpret),
@@ -97,7 +95,7 @@ def bitonic_sort_pairs(keys, vals, interpret=None):
 
 
 def l2_rerank(queries, candidates, metric="l2", interpret=None):
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     return _instrumented(
         "l2_rerank", (queries, candidates),
         lambda: _l2_rerank(queries, candidates, metric=metric,

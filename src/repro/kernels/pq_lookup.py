@@ -1,19 +1,18 @@
 """Pallas TPU kernel: PQ distance evaluation, Eq. (3) of the paper.
 
 The ASIC's per-queue "Distance Computation Module" does M SRAM lookups + an
-M-term accumulation per candidate. TPUs have no efficient VMEM gather, but
-they have an MXU — so the lookup is re-expressed as a ONE-HOT MATMUL
-(DESIGN.md §2, hardware adaptation):
+M-term accumulation per candidate. TPUs have no efficient VMEM gather, so
+each lookup is a compare-and-select (DESIGN.md §2, hardware adaptation):
 
     dist[n] = sum_m ADT[m, codes[n, m]]
-            = onehot(codes)[n, :] . vec(ADT)      with onehot in {0,1}^(M*C)
+            = sum_m sum_c [codes[n, m] == c] * ADT[m, c]
 
-The one-hot block is built in-register from a broadcasted iota comparison —
-it never exists in HBM. Per grid step the kernel holds a (NB, M) code tile,
-the full (M, C) ADT and the (NB, M, C) one-hot in VMEM:
-NB=128, M=32, C=256 -> 128*8192*4 B = 4 MB (fits v5e's 16 MB VMEM twice over
-for double buffering). The contraction is a (NB, M*C) x (M*C, 1) matvec on
-the MXU with f32 accumulation.
+Per grid step the kernel holds a (NB, M) code tile and the full (M, C) ADT
+in VMEM and accumulates over the M subspaces one (NB, C) select + lane
+reduction at a time, so no 3-D one-hot is ever built or reshaped (Mosaic
+refuses the (NB, M, C) -> (NB, M*C) shape cast). The output is an (NB, 1)
+column per block: its last dim equals the array's, which keeps the block
+legal under the vmap that ``core.search`` wraps around the call.
 """
 from __future__ import annotations
 
@@ -27,15 +26,14 @@ from jax.experimental import pallas as pl
 def _lookup_kernel(codes_ref, adt_ref, out_ref):
     codes = codes_ref[...].astype(jnp.int32)        # (NB, M)
     adt = adt_ref[...]                              # (M, C)
-    m, c = adt.shape
-    iota = jax.lax.broadcasted_iota(jnp.int32, (codes.shape[0], m, c), 2)
-    onehot = (codes[:, :, None] == iota).astype(jnp.float32)   # in-register
-    flat = onehot.reshape(codes.shape[0], m * c)
-    out_ref[...] = jax.lax.dot_general(
-        flat, adt.reshape(m * c, 1),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[:, 0]
+    nb, m = codes.shape
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (nb, adt.shape[1]), 1)
+    acc = jnp.zeros((nb, 1), jnp.float32)
+    for j in range(m):
+        hit = codes[:, j:j + 1] == lanes            # (NB, C)
+        acc = acc + jnp.where(hit, adt[j:j + 1, :], 0.0).sum(
+            axis=1, keepdims=True)
+    out_ref[...] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("n_block", "interpret"))
@@ -48,6 +46,7 @@ def pq_lookup(
     """Returns (N,) float32 PQ distances."""
     n, m = codes.shape
     _, c = adt.shape
+    n_block = min(n_block, -(-n // 8) * 8)      # small N: one 8-row tile
     pad = (-n) % n_block
     if pad:
         codes = jnp.pad(codes, ((0, pad), (0, 0)))
@@ -59,8 +58,8 @@ def pq_lookup(
             pl.BlockSpec((n_block, m), lambda i: (i, 0)),
             pl.BlockSpec((m, c), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((n_block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((np_,), jnp.float32),
+        out_specs=pl.BlockSpec((n_block, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((np_, 1), jnp.float32),
         interpret=interpret,
     )(codes, adt)
-    return out[:n]
+    return out[:n, 0]

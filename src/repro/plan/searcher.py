@@ -295,8 +295,11 @@ class Searcher:
 
     @classmethod
     def _open_distributed(cls, dcorpus, pc, metric, mesh, obs):
+        from repro.core.distributed import place_corpus
+
         if mesh is None:
             raise ValueError("distributed targets need mesh=")
+        dcorpus = place_corpus(dcorpus, mesh, pc.data_axis)
         scfg = cls._resolve_cfg(pc, pc.search or SearchConfig())
         caps = IndexCapabilities(
             kind="distributed", mesh_devices=int(mesh.size),
