@@ -192,13 +192,14 @@ def _build_adts(corpus: Corpus, queries: jnp.ndarray, cfg: SearchConfig,
     while_loop kernel and ``init_search_state``."""
     if not cfg.use_pq:
         return jnp.zeros((queries.shape[0], 1, 1), jnp.float32)
-    if cfg.use_pallas:
-        from repro.kernels import ops
+    with jax.named_scope("adt-build"):
+        if cfg.use_pallas:
+            from repro.kernels import ops
 
-        return ops.pq_adt(queries, corpus.centroids, metric)
-    return jax.vmap(lambda q: compute_adt(q, corpus.centroids, metric))(
-        queries
-    )
+            return ops.pq_adt(queries, corpus.centroids, metric)
+        return jax.vmap(lambda q: compute_adt(q, corpus.centroids, metric))(
+            queries
+        )
 
 
 def _round_fns(corpus: Corpus, cfg: SearchConfig, metric: str,
@@ -215,7 +216,15 @@ def _round_fns(corpus: Corpus, cfg: SearchConfig, metric: str,
     per lane" — so one ``graph_search_step`` application IS one iteration of
     the vmapped loop, and iterating it until no lane is active reproduces
     the loop's fixpoint exactly (extra steps on a finished batch are
-    no-ops)."""
+    no-ops).
+
+    The round's parts carry ``jax.named_scope`` names (``beam-select``,
+    ``gather-adjacency``, ``gather-codes``, ``pq-lookup``, ``merge-sort``,
+    ``gather-base``, ``rerank-exact``, ``topk-termination``,
+    ``state-select``; ``init-lanes`` for round 0, ``loop-cond`` for the
+    activity test) so that the device trace can attribute each op's time
+    to one of them; scopes change only the HLO metadata, not the compiled
+    program."""
     cfg = upgrade_config(cfg)    # pre-beam pickled configs: fill defaults
     L, k = cfg.list_size, cfg.k
     R = corpus.adjacency.shape[1]
@@ -228,114 +237,138 @@ def _round_fns(corpus: Corpus, cfg: SearchConfig, metric: str,
 
     def tdist(q, adt, ids):
         if use_pq:
-            if cfg.use_pallas:
-                from repro.kernels import ops
+            with jax.named_scope("gather-codes"):
+                codes = corpus.codes[ids]
+            with jax.named_scope("pq-lookup"):
+                if cfg.use_pallas:
+                    from repro.kernels import ops
 
-                return ops.pq_lookup(corpus.codes[ids], adt)
-            return pq_distance(corpus.codes[ids], adt)
-        return _exact_dist(q, corpus.base[ids], metric)
+                    return ops.pq_lookup(codes, adt)
+                return pq_distance(codes, adt)
+        with jax.named_scope("gather-base"):
+            rows = corpus.base[ids]
+        with jax.named_scope("rerank-exact"):
+            return _exact_dist(q, rows, metric)
 
     def init_one(q, adt):
-        ep = corpus.entry_point
-        d0 = tdist(q, adt, ep[None])[0]
-        ids0 = jnp.full((L,), -1, jnp.int32).at[0].set(ep)
-        dists0 = jnp.full((L,), INF).at[0].set(d0)
-        acc0 = jnp.full((L,), INF)
-        if not use_pq:
-            acc0 = acc0.at[0].set(d0)
-        bits0 = bloom.bloom_init(bloom_bits)
-        bits0 = bloom.insert(bits0, ep[None], jnp.ones((1,), bool), num_hashes)
-
-        return _State(
-            ids=ids0, dists=dists0, acc=acc0,
-            evaluated=jnp.zeros((L,), bool), bits=bits0,
-            t=jnp.int32(min(t_init, L)),
-            prev_topk=jnp.full((k,), -2, jnp.int32),
-            stable=jnp.int32(0), done=jnp.bool_(False),
-            n_hops=jnp.int32(0), n_pq=jnp.int32(1 if use_pq else 0),
-            n_acc=jnp.int32(0 if use_pq else 1),
-            n_hot=jnp.int32(0), n_free=jnp.int32(0), rounds=jnp.int32(0),
-        )
+        with jax.named_scope("init-lanes"):
+            ep = corpus.entry_point
+            d0 = tdist(q, adt, ep[None])[0]
+            ids0 = jnp.full((L,), -1, jnp.int32).at[0].set(ep)
+            dists0 = jnp.full((L,), INF).at[0].set(d0)
+            acc0 = jnp.full((L,), INF)
+            if not use_pq:
+                acc0 = acc0.at[0].set(d0)
+            bits0 = bloom.bloom_init(bloom_bits)
+            bits0 = bloom.insert(bits0, ep[None], jnp.ones((1,), bool),
+                                 num_hashes)
+            return _State(
+                ids=ids0, dists=dists0, acc=acc0,
+                evaluated=jnp.zeros((L,), bool), bits=bits0,
+                t=jnp.int32(min(t_init, L)),
+                prev_topk=jnp.full((k,), -2, jnp.int32),
+                stable=jnp.int32(0), done=jnp.bool_(False),
+                n_hops=jnp.int32(0), n_pq=jnp.int32(1 if use_pq else 0),
+                n_acc=jnp.int32(0 if use_pq else 1),
+                n_hot=jnp.int32(0), n_free=jnp.int32(0), rounds=jnp.int32(0),
+            )
 
     def cond(s: _State):
-        return (~s.done) & (s.rounds < cfg.max_rounds)
+        with jax.named_scope("loop-cond"):
+            return (~s.done) & (s.rounds < cfg.max_rounds)
 
     def body(q, adt, s: _State):
-        valid = s.ids >= 0
-        unev = valid & ~s.evaluated
-        n_unev = unev.sum()
-        has_unev = unev.any()
-        # positions of unevaluated entries in list (distance) order: a
-        # stable sort of ~unev floats them to the front, so sel[:E] are
-        # the E best unevaluated candidates — the round's beam. E == 1
-        # keeps the original O(L) argmax instead of the O(L log L) sort.
-        if E == 1:
-            sel = jnp.argmax(unev)[None]               # (1,)
-        else:
-            sel = jnp.argsort(~unev, stable=True)[:E]  # (E,) distinct
-        sel_valid = jnp.arange(E) < n_unev             # (E,)
-        vs = jnp.where(sel_valid, s.ids[sel], 0)       # (E,) beam ids
+        # scopes follow the round's own order, so the traced program is the
+        # one it was before they were added
+        with jax.named_scope("beam-select"):
+            valid = s.ids >= 0
+            unev = valid & ~s.evaluated
+            n_unev = unev.sum()
+            has_unev = unev.any()
+            # positions of unevaluated entries in list (distance) order: a
+            # stable sort of ~unev floats them to the front, so sel[:E] are
+            # the E best unevaluated candidates — the round's beam. E == 1
+            # keeps the original O(L) argmax instead of the O(L log L) sort.
+            if E == 1:
+                sel = jnp.argmax(unev)[None]               # (1,)
+            else:
+                sel = jnp.argsort(~unev, stable=True)[:E]  # (E,) distinct
+            sel_valid = jnp.arange(E) < n_unev             # (E,)
+            vs = jnp.where(sel_valid, s.ids[sel], 0)       # (E,) beam ids
 
         # ---- expand the beam: one E-row adjacency gather ---------------
-        neigh = corpus.adjacency[vs].reshape(E * R)    # (E*R,)
-        fresh = _dedup_round(neigh) & ~bloom.contains(s.bits, neigh, num_hashes)
-        fresh = fresh & jnp.repeat(sel_valid, R)
+        with jax.named_scope("gather-adjacency"):
+            neigh = corpus.adjacency[vs].reshape(E * R)    # (E*R,)
+            fresh = _dedup_round(neigh) \
+                & ~bloom.contains(s.bits, neigh, num_hashes)
+            fresh = fresh & jnp.repeat(sel_valid, R)
         nd = tdist(q, adt, neigh)                      # one batched call
-        nd = jnp.where(fresh, nd, INF)
-        bits = bloom.insert(s.bits, neigh, fresh, num_hashes)
-        evaluated = s.evaluated.at[sel].set(s.evaluated[sel] | sel_valid)
-        n_new = fresh.sum()
-        is_hot = (vs < corpus.hot_count) & sel_valid   # (E,)
-        ids, dists, acc, evaluated = merge(
-            s.ids, s.dists, s.acc, evaluated,
-            jnp.where(fresh, neigh, -1).astype(jnp.int32), nd,
-        )
+        with jax.named_scope("pq-lookup"):
+            nd = jnp.where(fresh, nd, INF)
+        with jax.named_scope("gather-adjacency"):
+            bits = bloom.insert(s.bits, neigh, fresh, num_hashes)
+        with jax.named_scope("beam-select"):
+            evaluated = s.evaluated.at[sel].set(s.evaluated[sel] | sel_valid)
+            n_new = fresh.sum()
+            is_hot = (vs < corpus.hot_count) & sel_valid   # (E,)
+        with jax.named_scope("merge-sort"):
+            ids, dists, acc, evaluated = merge(
+                s.ids, s.dists, s.acc, evaluated,
+                jnp.where(fresh, neigh, -1).astype(jnp.int32), nd,
+            )
 
         # ---- top-T evaluated? -> rerank + early-termination ------------
-        valid = ids >= 0
-        pl = _passes_of(ids, node_mask)
-        in_t = (jnp.arange(L) < s.t) & valid
-        all_eval = jnp.where(in_t.any(), (~in_t | evaluated).all(), False)
+        with jax.named_scope("topk-termination"):
+            valid = ids >= 0
+            pl = _passes_of(ids, node_mask)
+            in_t = (jnp.arange(L) < s.t) & valid
+            all_eval = jnp.where(in_t.any(), (~in_t | evaluated).all(),
+                                 False)
+            # only passing candidates are admitted to the reranked top-k
+            # (non-passing ones still route; in_t implies valid, so with no
+            # mask in_t & pl == in_t and this is the unfiltered arithmetic)
+            need = in_t & pl & jnp.isinf(acc)
+        with jax.named_scope("gather-base"):
+            rows = corpus.base[jnp.maximum(ids, 0)]
+        with jax.named_scope("rerank-exact"):
+            acc_new = _exact_dist(q, rows, metric)
+            acc2 = jnp.where(need & all_eval, acc_new, acc)
+            n_acc_new = jnp.where(all_eval, need.sum(), 0)
+            if use_pq:
+                rerank_key = jnp.where(in_t & pl, acc2, INF)
+            else:
+                acc2 = jnp.where(valid, dists, INF)
+                rerank_key = jnp.where(in_t & pl, acc2, INF)
+        with jax.named_scope("topk-termination"):
+            new_topk = _topk_ids_by(ids, rerank_key, k)
+            same = (new_topk == s.prev_topk).all()
+            stable = jnp.where(all_eval, jnp.where(same, s.stable + 1, 1),
+                               s.stable)
+            prev_topk = jnp.where(all_eval, new_topk, s.prev_topk)
+            t = jnp.where(all_eval, s.t + t_step, s.t)
 
-        # only passing candidates are admitted to the reranked top-k
-        # (non-passing ones still route; in_t implies valid, so with no
-        # mask in_t & pl == in_t and this is the unfiltered arithmetic)
-        need = in_t & pl & jnp.isinf(acc)
-        acc_new = _exact_dist(q, corpus.base[jnp.maximum(ids, 0)], metric)
-        acc2 = jnp.where(need & all_eval, acc_new, acc)
-        n_acc_new = jnp.where(all_eval, need.sum(), 0)
-        if use_pq:
-            rerank_key = jnp.where(in_t & pl, acc2, INF)
-        else:
-            acc2 = jnp.where(valid, dists, INF)
-            rerank_key = jnp.where(in_t & pl, acc2, INF)
-        new_topk = _topk_ids_by(ids, rerank_key, k)
-        same = (new_topk == s.prev_topk).all()
-        stable = jnp.where(all_eval, jnp.where(same, s.stable + 1, 1), s.stable)
-        prev_topk = jnp.where(all_eval, new_topk, s.prev_topk)
-        t = jnp.where(all_eval, s.t + t_step, s.t)
+            terminated = do_et & all_eval & (stable >= cfg.repetition_rate)
+            exhausted = ~has_unev
+            overflow = t > L
+            done = terminated | exhausted | overflow
 
-        terminated = do_et & all_eval & (stable >= cfg.repetition_rate)
-        exhausted = ~has_unev
-        overflow = t > L
-        done = terminated | exhausted | overflow
-
-        hot_new = (fresh.reshape(E, R) & is_hot[:, None]).sum()
-        new = _State(
-            ids=ids, dists=dists, acc=acc2, evaluated=evaluated, bits=bits,
-            t=jnp.minimum(t, L), prev_topk=prev_topk, stable=stable,
-            done=done,
-            n_hops=s.n_hops + jnp.minimum(n_unev, E).astype(jnp.int32),
-            n_pq=s.n_pq + (n_new if use_pq else 0),
-            n_acc=s.n_acc + n_acc_new + (0 if use_pq else n_new),
-            n_hot=s.n_hot + is_hot.sum().astype(jnp.int32),
-            n_free=s.n_free + hot_new,
-            rounds=s.rounds + 1,
-        )
+            hot_new = (fresh.reshape(E, R) & is_hot[:, None]).sum()
+            new = _State(
+                ids=ids, dists=dists, acc=acc2, evaluated=evaluated,
+                bits=bits, t=jnp.minimum(t, L), prev_topk=prev_topk,
+                stable=stable, done=done,
+                n_hops=s.n_hops + jnp.minimum(n_unev, E).astype(jnp.int32),
+                n_pq=s.n_pq + (n_new if use_pq else 0),
+                n_acc=s.n_acc + n_acc_new + (0 if use_pq else n_new),
+                n_hot=s.n_hot + is_hot.sum().astype(jnp.int32),
+                n_free=s.n_free + hot_new,
+                rounds=s.rounds + 1,
+            )
         # lanes that were already done keep their state (vmap-safety)
-        return jax.tree_util.tree_map(
-            lambda a, b: jnp.where(s.done, a, b), s, new
-        )
+        with jax.named_scope("state-select"):
+            return jax.tree_util.tree_map(
+                lambda a, b: jnp.where(s.done, a, b), s, new
+            )
 
     return init_one, cond, body
 
@@ -385,53 +418,56 @@ def _finalize_batch(corpus: Corpus, cfg: SearchConfig, metric: str,
     state (Alg.1 l.19-22) — shared verbatim by the while_loop kernel and the
     round-step path's ``finalize_search``."""
     L, k = cfg.list_size, cfg.k
-    # ---- final beta rerank, batched (Alg.1 l.19-21; Pallas l2_rerank) ------
-    valid = s.ids >= 0                                       # (Q, L)
-    pass_l = _passes_of(s.ids, node_mask)                    # (Q, L)
-    if node_mask is None:
-        t_idx = jnp.clip(s.t, 1, L) - 1
-        d_t = jnp.take_along_axis(s.dists, t_idx[:, None], 1)[:, 0]
-        thr = d_t + (cfg.beta - 1.0) * jnp.abs(d_t)          # sign-safe margin
-    else:
-        # margin anchor = the T-th PASSING candidate's distance. The list
-        # is distance-sorted with all valid entries a prefix, so with an
-        # all-true mask "T-th passing" is exactly position T-1 (or the +inf
-        # padding when fewer than T are valid) — bit-identical to the
-        # unfiltered read above.
-        rank = jnp.cumsum(pass_l, axis=1)                    # (Q, L)
-        tt = jnp.clip(s.t, 1, L)
-        is_t = pass_l & (rank == tt[:, None])
-        d_t = jnp.where(is_t, s.dists, -INF).max(axis=1)
-        d_t = jnp.where(rank[:, -1] >= tt, d_t, INF)
-        # inf anchor (fewer than T passing): rerank every passing candidate
-        # — guarded, since beta == 1.0 would turn inf + 0*inf into NaN and
-        # silently drop all results
-        thr = jnp.where(jnp.isinf(d_t), INF,
-                        d_t + (cfg.beta - 1.0) * jnp.abs(d_t))
-    if cfg.use_pq and cfg.rerank:
-        need = pass_l & (s.dists <= thr[:, None]) & jnp.isinf(s.acc)
-        cand = corpus.base[jnp.maximum(s.ids, 0)]            # (Q, L, D)
-        if cfg.use_pallas:
-            from repro.kernels import ops
-
-            acc_new = ops.l2_rerank(queries, cand, metric)
+    with jax.named_scope("final-rerank"):
+        # ---- final beta rerank, batched (Alg.1 l.19-21; Pallas l2_rerank)
+        valid = s.ids >= 0                                       # (Q, L)
+        pass_l = _passes_of(s.ids, node_mask)                    # (Q, L)
+        if node_mask is None:
+            t_idx = jnp.clip(s.t, 1, L) - 1
+            d_t = jnp.take_along_axis(s.dists, t_idx[:, None], 1)[:, 0]
+            thr = d_t + (cfg.beta - 1.0) * jnp.abs(d_t)      # sign-safe margin
         else:
-            acc_new = jax.vmap(lambda q, x: _exact_dist(q, x, metric))(
-                queries, cand
-            )
-        acc = jnp.where(need, acc_new, s.acc)
-        n_acc = s.n_acc + need.sum(axis=1)
-    else:
-        # no rerank (rank by PQ) / accurate traversal (dists are accurate)
-        acc = jnp.where(valid, s.dists, INF)
-        n_acc = s.n_acc
-    key = jnp.where(pass_l, acc, INF)
-    neg, idx = jax.lax.top_k(-key, k)
-    out_ids = jnp.take_along_axis(s.ids, idx, 1)
-    if node_mask is not None:
-        # a filter can leave fewer than k admissible candidates: such slots
-        # carry +inf keys and must come back as explicit -1 padding
-        out_ids = jnp.where(jnp.isinf(neg), -1, out_ids)
+            # margin anchor = the T-th PASSING candidate's distance. The
+            # list is distance-sorted with all valid entries a prefix, so
+            # with an all-true mask "T-th passing" is exactly position T-1
+            # (or the +inf padding when fewer than T are valid) —
+            # bit-identical to the unfiltered read above.
+            rank = jnp.cumsum(pass_l, axis=1)                    # (Q, L)
+            tt = jnp.clip(s.t, 1, L)
+            is_t = pass_l & (rank == tt[:, None])
+            d_t = jnp.where(is_t, s.dists, -INF).max(axis=1)
+            d_t = jnp.where(rank[:, -1] >= tt, d_t, INF)
+            # inf anchor (fewer than T passing): rerank every passing
+            # candidate — guarded, since beta == 1.0 would turn inf + 0*inf
+            # into NaN and silently drop all results
+            thr = jnp.where(jnp.isinf(d_t), INF,
+                            d_t + (cfg.beta - 1.0) * jnp.abs(d_t))
+        if cfg.use_pq and cfg.rerank:
+            need = pass_l & (s.dists <= thr[:, None]) & jnp.isinf(s.acc)
+            cand = corpus.base[jnp.maximum(s.ids, 0)]        # (Q, L, D)
+            if cfg.use_pallas:
+                from repro.kernels import ops
+
+                acc_new = ops.l2_rerank(queries, cand, metric)
+            else:
+                acc_new = jax.vmap(lambda q, x: _exact_dist(q, x, metric))(
+                    queries, cand
+                )
+            acc = jnp.where(need, acc_new, s.acc)
+            n_acc = s.n_acc + need.sum(axis=1)
+        else:
+            # no rerank (rank by PQ) / accurate traversal (dists are
+            # accurate)
+            acc = jnp.where(valid, s.dists, INF)
+            n_acc = s.n_acc
+    with jax.named_scope("final-topk"):
+        key = jnp.where(pass_l, acc, INF)
+        neg, idx = jax.lax.top_k(-key, k)
+        out_ids = jnp.take_along_axis(s.ids, idx, 1)
+        if node_mask is not None:
+            # a filter can leave fewer than k admissible candidates: such slots
+            # carry +inf keys and must come back as explicit -1 padding
+            out_ids = jnp.where(jnp.isinf(neg), -1, out_ids)
     return SearchResult(
         ids=out_ids, dists=-neg, n_hops=s.n_hops, n_pq=s.n_pq, n_acc=n_acc,
         n_hot_hops=s.n_hot, n_free_pq=s.n_free, rounds=s.rounds,
@@ -520,9 +556,10 @@ def graph_search_step(
     def step_one(q, adt, s):
         active = cond(s)
         new = body(q, adt, s)
-        return jax.tree_util.tree_map(
-            lambda a, b: jnp.where(active, b, a), s, new
-        )
+        with jax.named_scope("state-select"):
+            return jax.tree_util.tree_map(
+                lambda a, b: jnp.where(active, b, a), s, new
+            )
 
     lanes = jax.vmap(step_one)(state.queries, state.adts, state.lanes)
     return state._replace(lanes=lanes)

@@ -28,6 +28,7 @@ from repro.obs.quality import (
     QualityMonitor, SLOTarget, SLOTracker, wilson_interval,
 )
 from repro.obs.registry import Histogram, MetricsRegistry, NULL_REGISTRY
+from repro.obs.syncs import SYNC_SITES, DeviceSyncs
 from repro.obs.tracing import NULL_SPAN, NULL_TRACER, Span, Tracer
 
 
@@ -122,6 +123,7 @@ NULL_OBS = Observability(metrics=NULL_REGISTRY, tracer=NULL_TRACER,
 
 __all__ = [
     "ConvergenceLog",
+    "DeviceSyncs",
     "Histogram",
     "KernelWatch",
     "MetricsRegistry",
@@ -134,6 +136,7 @@ __all__ = [
     "RecompileWarning",
     "SLOTarget",
     "SLOTracker",
+    "SYNC_SITES",
     "Span",
     "Tracer",
     "default_kernel_sources",

@@ -3,8 +3,8 @@
 JAX recompiles silently: a stray non-bucketed batch shape, a config object
 that stopped hashing stably, or a weak-typed scalar can multiply compiles
 and turn a serving loop into a trace loop.  ``KernelWatch`` samples each
-registered kernel's executable-cache size into the metrics registry
-(``jit_cache_entries{kernel=...}``) and warns — :class:`RecompileWarning` —
+registered kernel's executable-cache growth into the metrics registry
+(``jit_cache_growth{kernel=...}``) and warns — :class:`RecompileWarning` —
 when a kernel exceeds its expected entry budget (for the serving engine:
 ``log2(batch_size)+1`` power-of-two buckets per distinct executed plan
 config, the invariant the pow2-bucket compile-count test asserts).
@@ -57,13 +57,12 @@ class KernelWatch:
         self.baseline[name] = int(cache_size())
 
     def sample(self) -> Dict[str, int]:
-        """Record every kernel's current cache size as a gauge; returns
-        ``{kernel: entries}``."""
+        """Record every kernel's cache growth since construction as a gauge;
+        returns ``{kernel: entries}``."""
         out = {}
         for name, fn in self.sources.items():
             n = int(fn())
             out[name] = n
-            self.registry.gauge("jit_cache_entries", n, kernel=name)
             self.registry.gauge("jit_cache_growth", n - self.baseline[name],
                                 kernel=name)
         return out

@@ -15,16 +15,32 @@ A :class:`Tracer` records two event shapes:
 (load it in ``chrome://tracing`` or https://ui.perfetto.dev), with process/
 thread metadata events naming the tracks.
 
+Two sinks: every sync span of an enabled tracer also opens a
+``jax.profiler.TraceAnnotation`` named ``repro.<span name>`` and closes it
+when the span ends, so under an active profiler session the span sits in
+the profiler's trace on the same clock as the device's ops (with no session
+the annotation costs well under a microsecond).  Async spans and instants
+stay in the Chrome export only.
+
+The event buffer is bounded (``MAX_EVENTS``): past it the oldest events are
+dropped and counted in ``dropped``, so a long-running server with tracing
+on does not grow without limit.
+
 Zero-cost-when-off: a disabled tracer hands back one shared no-op span
-object — no allocation, no clock read.
+object — no allocation, no clock read, no annotation.
 """
 from __future__ import annotations
 
 import json
 import time
+from collections import deque
 from typing import List, Optional
 
 ENGINE_TID = 0          # the serving engine's synchronous track
+#: events kept per tracer; older ones are dropped (and counted) past it
+MAX_EVENTS = 1 << 18
+#: prefix of a span's name in the profiler's trace
+ANNOTATION_PREFIX = "repro."
 
 
 class _NullSpan:
@@ -51,7 +67,7 @@ NULL_SPAN = _NullSpan()
 class Span:
     """One in-flight sync span; closes into a complete ("X") trace event."""
 
-    __slots__ = ("_tracer", "name", "cat", "tid", "args", "ts")
+    __slots__ = ("_tracer", "name", "cat", "tid", "args", "ts", "_anno")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, tid: int,
                  args: dict):
@@ -60,6 +76,8 @@ class Span:
         self.cat = cat
         self.tid = tid
         self.args = args
+        self._anno = tracer._annotation(ANNOTATION_PREFIX + name)
+        self._anno.__enter__()
         self.ts = tracer._now_us()
 
     def set(self, **args) -> None:
@@ -68,9 +86,11 @@ class Span:
 
     def end(self) -> None:
         t = self._tracer
-        t._events.append({
+        dur = max(t._now_us() - self.ts, 0.0)
+        self._anno.__exit__(None, None, None)
+        t._append({
             "name": self.name, "cat": self.cat, "ph": "X",
-            "ts": self.ts, "dur": max(t._now_us() - self.ts, 0.0),
+            "ts": self.ts, "dur": dur,
             "pid": t.pid, "tid": self.tid, "args": self.args,
         })
 
@@ -87,8 +107,13 @@ class Tracer:
         self.enabled = enabled
         self.pid = pid
         self._epoch = time.perf_counter()
-        self._events: List[dict] = []
+        self._meta_events: List[dict] = []
+        self._events: deque = deque(maxlen=MAX_EVENTS)
+        self.dropped = 0
         if enabled:
+            from jax.profiler import TraceAnnotation
+
+            self._annotation = TraceAnnotation
             self._meta("process_name", ENGINE_TID, name="repro-serving")
             self._meta("thread_name", ENGINE_TID, name="engine")
 
@@ -96,8 +121,13 @@ class Tracer:
         return (time.perf_counter() - self._epoch) * 1e6
 
     def _meta(self, kind: str, tid: int, **args) -> None:
-        self._events.append({"name": kind, "ph": "M", "pid": self.pid,
-                             "tid": tid, "args": args})
+        self._meta_events.append({"name": kind, "ph": "M", "pid": self.pid,
+                                  "tid": tid, "args": args})
+
+    def _append(self, event: dict) -> None:
+        if len(self._events) == self._events.maxlen:
+            self.dropped += 1
+        self._events.append(event)
 
     # ------------------------------------------------------------ sync spans
     def span(self, name: str, cat: str = "serve", tid: int = ENGINE_TID,
@@ -112,7 +142,7 @@ class Tracer:
                     **args) -> None:
         if not self.enabled:
             return
-        self._events.append({
+        self._append({
             "name": name, "cat": cat, "ph": "b", "id": id,
             "ts": self._now_us(), "pid": self.pid, "tid": ENGINE_TID,
             "args": args,
@@ -122,7 +152,7 @@ class Tracer:
                   **args) -> None:
         if not self.enabled:
             return
-        self._events.append({
+        self._append({
             "name": name, "cat": cat, "ph": "e", "id": id,
             "ts": self._now_us(), "pid": self.pid, "tid": ENGINE_TID,
             "args": args,
@@ -132,7 +162,7 @@ class Tracer:
         """Zero-duration marker (consolidation points, warnings...)."""
         if not self.enabled:
             return
-        self._events.append({
+        self._append({
             "name": name, "cat": cat, "ph": "i", "s": "g",
             "ts": self._now_us(), "pid": self.pid, "tid": ENGINE_TID,
             "args": args,
@@ -140,7 +170,8 @@ class Tracer:
 
     # --------------------------------------------------------------- reading
     def events(self) -> List[dict]:
-        return list(self._events)
+        """Metadata events, then the buffered ones, oldest first."""
+        return self._meta_events + list(self._events)
 
     def export(self, path: Optional[str] = None) -> dict:
         """Chrome trace-event JSON object; written to ``path`` if given."""
@@ -151,8 +182,10 @@ class Tracer:
         return doc
 
     def clear(self) -> None:
-        keep = [e for e in self._events if e.get("ph") == "M"]
-        self._events = keep
+        """Drop every recorded event (and the drop count); the track
+        metadata stays."""
+        self._events.clear()
+        self.dropped = 0
 
 
 #: the shared disabled tracer — every call is a no-op

@@ -41,7 +41,7 @@ from repro.configs.base import (
     FilterConfig, PlanConfig, SearchConfig, upgrade_config,
 )
 from repro.filter.spec import FilterSpec
-from repro.obs import NULL_OBS, Observability
+from repro.obs import NULL_OBS, DeviceSyncs, Observability
 from repro.plan.request import SearchRequest, SearchStats
 
 
@@ -180,6 +180,8 @@ class QueryPlanner:
         self.plan_cache_hits = 0
         self.plan_cache_misses = 0
         self.obs = obs or NULL_OBS
+        # every blocking device->host read of this target's served path
+        self.syncs = DeviceSyncs(self.obs)
 
     # ------------------------------------------------------------- planning
     def plan(self, request: SearchRequest) -> QueryPlan:
@@ -436,18 +438,20 @@ class QueryPlanner:
         SAME kernels, with the SAME arguments, as the legacy entry point the
         plan replaces (the bit-identity contract).
 
-        With observability enabled the dispatch is wrapped in a
+        With observability enabled the execution is wrapped in a
         ``kernel-execute`` span and billed into ``kernel_execute_ms``
-        (labeled by plan kind / filter strategy / tenant); the traversal
-        rounds inside the compiled while_loop are not individually
-        observable, so the span carries the whole device execution."""
+        (labeled by plan kind / filter strategy / tenant).  Inside it,
+        ``dispatch`` covers the kernel call up to its return, and the
+        result fetch (``DeviceSyncs.get``) the wait for the device and the
+        copy to the host; the traversal rounds inside the compiled
+        while_loop show only in the device trace, by named scope."""
         obs = self.obs
         if not obs.enabled:
             return self._execute_plan(plan, queries)
         import time as _time
 
         t0 = _time.perf_counter()
-        with obs.tracer.span("kernel-execute", kind=plan.kind,
+        with obs.tracer.span("kernel-execute", cat="plan", kind=plan.kind,
                              strategy=plan.strategy) as sp:
             ex = self._execute_plan(plan, queries)
             sp.set(queries=int(np.atleast_2d(np.asarray(ex.ids)).shape[0]))
@@ -455,36 +459,39 @@ class QueryPlanner:
             "kernel_execute_ms", (_time.perf_counter() - t0) * 1e3,
             kind=plan.kind, strategy=plan.strategy, tenant=plan.tenant,
         )
-        obs.metrics.counter("kernel_executions", kind=plan.kind,
-                            strategy=plan.strategy, tenant=plan.tenant)
         return ex
 
     def _execute_plan(self, plan: QueryPlan, queries) -> Execution:
-        import jax
         import jax.numpy as jnp
 
+        tracer, syncs = self.obs.tracer, self.syncs
         if plan.kind == "distributed":
             from repro.core.distributed import distributed_search_kernel
 
             pc = self.plan_cfg
-            ids, dists = distributed_search_kernel(
-                self.dcorpus, queries, plan.cfg, self.metric, pc.mode,
-                mesh=self.mesh, data_axis=pc.data_axis,
-                queue_axis=pc.queue_axis, bloom_bits=pc.bloom_bits,
-                num_hashes=pc.num_hashes,
-            )
-            return Execution(ids=np.asarray(ids), dists=np.asarray(dists),
-                             raw=(ids, dists), counters=None,
+            with tracer.span("dispatch", cat="plan"):
+                raw = distributed_search_kernel(
+                    self.dcorpus, queries, plan.cfg, self.metric, pc.mode,
+                    mesh=self.mesh, data_axis=pc.data_axis,
+                    queue_axis=pc.queue_axis, bloom_bits=pc.bloom_bits,
+                    num_hashes=pc.num_hashes,
+                )
+            ids, dists = syncs.get("execute", raw)
+            return Execution(ids=ids, dists=dists, raw=raw, counters=None,
                              selectivity=1.0, delta_candidates=0.0)
 
         q_np = np.atleast_2d(np.asarray(queries, np.float32))
         if plan.kind == "merged":
             from repro.stream.searcher import merged_search_kernel
 
-            res = merged_search_kernel(
-                self.mutable, q_np, plan.cfg,
-                probe_tiles=plan.probe_tiles or None, filter_spec=plan.spec,
-            )
+            # the merged kernel fetches and fuses on the host itself, so
+            # its dispatch span holds its waits and copies too
+            with tracer.span("dispatch", cat="plan"):
+                res = merged_search_kernel(
+                    self.mutable, q_np, plan.cfg,
+                    probe_tiles=plan.probe_tiles or None,
+                    filter_spec=plan.spec,
+                )
             return Execution(ids=res.ids, dists=res.dists, raw=res,
                              counters=res.base, selectivity=res.selectivity,
                              delta_candidates=float(
@@ -496,14 +503,15 @@ class QueryPlanner:
             node_masks = None
             if plan.strategy == "masked":
                 node_masks = self._artifacts_for(plan)["node_masks"]
-            res = sharded_search_kernel(
-                self.tiled, q_np, plan.cfg, self.metric,
-                use_vmap=self.plan_cfg.use_vmap,
-                probe_tiles=plan.probe_tiles or None, node_masks=node_masks,
-            )
-            jax.block_until_ready(res.ids)
-            return Execution(ids=np.asarray(res.ids),
-                             dists=np.asarray(res.dists), raw=res,
+            with tracer.span("dispatch", cat="plan"):
+                res = sharded_search_kernel(
+                    self.tiled, q_np, plan.cfg, self.metric,
+                    use_vmap=self.plan_cfg.use_vmap,
+                    probe_tiles=plan.probe_tiles or None,
+                    node_masks=node_masks,
+                )
+            ids, dists = syncs.get("execute", (res.ids, res.dists))
+            return Execution(ids=ids, dists=dists, raw=res,
                              counters=res, selectivity=plan.selectivity,
                              delta_candidates=0.0)
 
@@ -513,34 +521,38 @@ class QueryPlanner:
 
         pc = self.plan_cfg
         if plan.strategy == "none":
-            res = graph_search(self.corpus, q_np, plan.cfg, self.metric,
-                               pc.bloom_bits, pc.num_hashes)
-            jax.block_until_ready(res.ids)
-            return Execution(ids=np.asarray(res.ids),
-                             dists=np.asarray(res.dists), raw=res,
+            with tracer.span("dispatch", cat="plan"):
+                res = graph_search(self.corpus, q_np, plan.cfg, self.metric,
+                                   pc.bloom_bits, pc.num_hashes)
+            ids, dists = syncs.get("execute", (res.ids, res.dists))
+            return Execution(ids=ids, dists=dists, raw=res,
                              counters=res, selectivity=1.0,
                              delta_candidates=0.0)
         nq = q_np.shape[0]
-        if plan.strategy == "empty":
-            core = empty_search_result(nq, plan.cfg.k)
-            fres = FilteredSearchResult(
-                ids=np.asarray(core.ids), dists=np.asarray(core.dists),
-                result=core, mode="empty", selectivity=0.0, effective=plan.cfg,
-            )
-        elif plan.strategy == "scan":
+        if plan.strategy == "scan":
             mask = self._artifacts_for(plan)["mask"]
-            fres = scan_search(self.corpus, jnp.asarray(q_np), mask,
-                               plan.cfg, self.metric, self.filter_cfg,
-                               plan.selectivity)
-        else:                        # masked traversal, plan.cfg pre-adapted
-            mask = self._artifacts_for(plan)["mask"]
-            res = graph_search(self.corpus, jnp.asarray(q_np), plan.cfg,
-                               self.metric, pc.bloom_bits, pc.num_hashes,
-                               node_mask=jnp.asarray(mask))
+            # the bitmap scan fetches its candidates on the host itself
+            with tracer.span("dispatch", cat="plan"):
+                fres = scan_search(self.corpus, jnp.asarray(q_np), mask,
+                                   plan.cfg, self.metric, self.filter_cfg,
+                                   plan.selectivity)
+        else:
+            if plan.strategy == "empty":
+                with tracer.span("dispatch", cat="plan"):
+                    core = empty_search_result(nq, plan.cfg.k)
+                mode, sel = "empty", 0.0
+            else:                    # masked traversal, plan.cfg pre-adapted
+                mask = self._artifacts_for(plan)["mask"]
+                with tracer.span("dispatch", cat="plan"):
+                    core = graph_search(self.corpus, jnp.asarray(q_np),
+                                        plan.cfg, self.metric, pc.bloom_bits,
+                                        pc.num_hashes,
+                                        node_mask=jnp.asarray(mask))
+                mode, sel = "traversal", plan.selectivity
+            ids, dists = syncs.get("execute", (core.ids, core.dists))
             fres = FilteredSearchResult(
-                ids=np.asarray(res.ids), dists=np.asarray(res.dists),
-                result=res, mode="traversal", selectivity=plan.selectivity,
-                effective=plan.cfg,
+                ids=ids, dists=dists, result=core, mode=mode,
+                selectivity=sel, effective=plan.cfg,
             )
         return Execution(ids=fres.ids, dists=fres.dists, raw=fres,
                          counters=fres.result, selectivity=fres.selectivity,

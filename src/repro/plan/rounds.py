@@ -75,39 +75,51 @@ class RoundSession:
         self.base_mode = base_mode
 
     # ------------------------------------------------------------- stepping
+    # Spans (category ``plan``) mark each device program's dispatch —
+    # ``init``, ``round``, ``finalize`` — and each blocking read goes through
+    # the planner's ``DeviceSyncs``.
     def init(self, queries):
         """Round 0 for a (Q, D) batch -> ``core.search.SearchState``."""
         import jax.numpy as jnp
 
         from repro.core.search import init_search_state
 
-        q = jnp.asarray(np.atleast_2d(np.asarray(queries, np.float32)))
-        return init_search_state(self.corpus, q, self.cfg, self.metric,
-                                 self.bloom_bits, self.num_hashes, self._mask)
+        with self.planner.obs.tracer.span("init", cat="plan"):
+            q = jnp.asarray(np.atleast_2d(np.asarray(queries, np.float32)))
+            return init_search_state(self.corpus, q, self.cfg, self.metric,
+                                     self.bloom_bits, self.num_hashes,
+                                     self._mask)
 
     def step(self, state):
         """ONE traversal round over every lane; quiet lanes pass through."""
         from repro.core.search import graph_search_step
 
-        return graph_search_step(self.corpus, state, self.cfg, self.metric,
-                                 self.bloom_bits, self.num_hashes, self._mask)
+        with self.planner.obs.tracer.span("round", cat="plan"):
+            return graph_search_step(self.corpus, state, self.cfg,
+                                     self.metric, self.bloom_bits,
+                                     self.num_hashes, self._mask)
 
     def active(self, state) -> np.ndarray:
         """(Q,) bool host array — lanes with rounds still to run."""
         from repro.core.search import search_state_active
 
-        return np.asarray(search_state_active(state, self.cfg))
+        tracer = self.planner.obs.tracer
+        with tracer.span("active-sync", cat="plan"):
+            with tracer.span("dispatch", cat="plan"):
+                active = search_state_active(state, self.cfg)
+            return self.planner.syncs.get("active", active)
 
     def rounds(self, state) -> np.ndarray:
         """(Q,) int host array — rounds each lane has executed so far."""
-        return np.asarray(state.lanes.rounds)
+        return self.planner.syncs.get("rounds", state.lanes.rounds)
 
     def finalize(self, state):
         """Beta rerank + top-k over the batch -> core ``SearchResult``."""
         from repro.core.search import finalize_search
 
-        return finalize_search(self.corpus, state, self.cfg, self.metric,
-                               self._mask)
+        with self.planner.obs.tracer.span("finalize", cat="plan"):
+            return finalize_search(self.corpus, state, self.cfg, self.metric,
+                                   self._mask)
 
     def record_round(self, log, qids, state, select=None) -> None:
         """Append one per-round telemetry record per (selected) lane to an

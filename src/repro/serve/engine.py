@@ -345,10 +345,12 @@ class ServingEngine:
     @property
     def stats(self) -> dict:
         """Back-compat dict view, derived from the structured
-        ``EngineStats`` with the planner's plan-cache counters merged in
-        at read time (the planner owns the cache; nothing is hand-synced)."""
+        ``EngineStats`` with the planner's plan-cache counters and its count
+        of blocking device->host reads (``device_syncs``) merged in at read
+        time (the planner owns both; nothing is hand-synced)."""
         d = self._stats.as_dict()
         d.update(self.searcher.plan_cache_stats())
+        d["device_syncs"] = self.searcher.planner.syncs.total
         return d
 
     def slo_status(self) -> dict:
@@ -370,15 +372,13 @@ class ServingEngine:
             filter = None                 # all-pass spec == unfiltered batch
         q = np.asarray(query, np.float32)
         obs = self.obs
-        with obs.tracer.span("plan-lookup", rid=rid):
-            try:
-                plan = self.searcher.plan(SearchRequest(queries=q,
-                                                        filter=filter,
-                                                        tenant=tenant))
-            except RuntimeError:
-                # missing attribute store: accept the request and surface the
-                # error at flush time, like the legacy engine did
-                plan = None
+        try:
+            plan = self.searcher.plan(SearchRequest(queries=q, filter=filter,
+                                                    tenant=tenant))
+        except RuntimeError:
+            # missing attribute store: accept the request and surface the
+            # error at flush time, like the legacy engine did
+            plan = None
         self.queue.append(Request(rid=rid, query=q,
                                   t_submit=time.perf_counter(),
                                   filter=filter, tenant=tenant, plan=plan))
@@ -539,8 +539,6 @@ class ServingEngine:
             if obs.enabled:
                 bsp.set(queries=n, bucket=bucket)
                 obs.metrics.gauge("batch_occupancy", n / bucket)
-                obs.metrics.observe("batch_occupancy_hist", n / bucket,
-                                    kind=plan.kind)
                 obs.metrics.gauge("queue_depth", float(len(self.queue)))
             if obs.nand_billing:
                 with obs.tracer.span("nand-billing"):
@@ -557,6 +555,15 @@ class ServingEngine:
                         nand=self.nand, batch_queries=n,
                         n_queues=self.nand_queues,
                     )
+            if self._watch is not None:
+                with obs.tracer.span("recompile-watch"):
+                    self._plan_keys_seen.add(key)
+                    self._watch.sample()
+                    # the pow2-bucket contract as a LIVE assertion: at most
+                    # log2(batch)+1 compiled shapes per distinct executed
+                    # plan
+                    buckets = int(math.log2(next_pow2(self.batch_size))) + 1
+                    self._watch.check(buckets * len(self._plan_keys_seen))
         # running MEAN pad fraction over all batches (a sum would grow
         # without bound and read as >100% padding after a few batches)
         b = self._stats.batches
@@ -565,13 +572,6 @@ class ServingEngine:
         ) / (b + 1)
         self._stats.batches = b + 1
         self._stats.queries += n
-        if self._watch is not None:
-            self._plan_keys_seen.add(key)
-            self._watch.sample()
-            # the pow2-bucket contract as a LIVE assertion: at most
-            # log2(batch)+1 compiled shapes per distinct executed plan
-            buckets = int(math.log2(next_pow2(self.batch_size))) + 1
-            self._watch.check(buckets * len(self._plan_keys_seen))
         if (
             self.auto_consolidate
             and self.mutable is not None
@@ -623,11 +623,15 @@ class ServingEngine:
             qmat[slot] = r.query
             refill[slot] = True
             pool.requests[slot] = r
+        tracer = self.obs.tracer
         fresh = pool.session.init(qmat)
-        state = fresh if pool.state is None \
-            else _select_lanes(refill, fresh, pool.state)
+        state = fresh
+        if pool.state is not None:
+            with tracer.span("select-lanes"):
+                state = _select_lanes(refill, fresh, pool.state)
         occupied = np.array([r is not None for r in pool.requests])
-        pool.state = _quiet_free_lanes(state, occupied)
+        with tracer.span("quiet-lanes"):
+            pool.state = _quiet_free_lanes(state, occupied)
 
     def _refill(self) -> None:
         """Admit queued requests into free slots, FIFO, creating slot pools
@@ -636,6 +640,10 @@ class ServingEngine:
         batch-flush fallback."""
         if not self.queue:
             return
+        with self.obs.tracer.span("refill"):
+            self._refill_queued()
+
+    def _refill_queued(self) -> None:
         obs = self.obs
         admitted: Dict[tuple, List[tuple]] = {}
         remaining: Deque[Request] = deque()
@@ -678,7 +686,8 @@ class ServingEngine:
                 )
         self.queue = remaining
         for key, admissions in admitted.items():
-            self._admit(self._pools[key], admissions)
+            with obs.tracer.span("admit", lanes=len(admissions)):
+                self._admit(self._pools[key], admissions)
             self._plan_keys_seen.add(key)
 
     def _step_pool(self, pool: _SlotPool) -> List[Request]:
@@ -687,67 +696,81 @@ class ServingEngine:
         like flushed ones (``RoundSession.complete`` returns the same
         plan-layer result shape)."""
         obs = self.obs
-        plan = pool.session.plan
         pool.state = pool.session.step(pool.state)
         if obs.convergence is not None:
             # per-round telemetry for every occupied lane — live requests
             # grow the same learned-ET dataset the off-line driver collects
             occ = [i for i, r in enumerate(pool.requests) if r is not None]
             if occ:
-                pool.session.record_round(
-                    obs.convergence,
-                    [pool.requests[i].rid for i in occ],
-                    pool.state, select=occ)
+                with obs.tracer.span("record-round"):
+                    pool.session.record_round(
+                        obs.convergence,
+                        [pool.requests[i].rid for i in occ],
+                        pool.state, select=occ)
         active = pool.session.active(pool.state)
         rows = [i for i, r in enumerate(pool.requests)
                 if r is not None and not active[i]]
         if not rows:
             return []
-        idx = np.asarray(rows)
-        bucket = next_pow2(len(rows))      # pad rows to a pow2 gather shape
-        pad = np.full((bucket,), rows[0], np.int64)
-        pad[: len(rows)] = rows
-        core = pool.session.finalize(_gather_rows(pool.state, pad))
-        core_rows = type(core)(*(np.asarray(f)[: len(rows)] for f in core))
-        qrows = np.stack([pool.requests[i].query for i in rows])
-        rounds = pool.session.rounds(pool.state)[idx]
+        plan = pool.session.plan
         with obs.tracer.span("retire", kind=plan.kind,
                              strategy=plan.strategy, lanes=len(rows)):
-            pres = pool.session.complete(qrows, core_rows)
+            return self._retire(pool, rows)
+
+    def _retire(self, pool: _SlotPool, rows: List[int]) -> List[Request]:
+        """Finalize the quiesced ``rows`` of a pool, complete their requests
+        and free their slots."""
+        obs = self.obs
+        session = pool.session
+        plan = session.plan
+        n = len(rows)
+        pad = np.full((next_pow2(n),), rows[0], np.int64)  # pow2 gather shape
+        pad[:n] = rows
+        with obs.tracer.span("gather-rows"):
+            gathered = _gather_rows(pool.state, pad)
+        core = self.searcher.planner.syncs.get("retire",
+                                               session.finalize(gathered))
+        core_rows = type(core)(*(f[:n] for f in core))
+        qrows = np.stack([pool.requests[i].query for i in rows])
+        rounds = session.rounds(pool.state)[np.asarray(rows)]
+        with obs.tracer.span("complete", cat="plan"):
+            pres = session.complete(qrows, core_rows)
         now = time.perf_counter()
         completed: List[Request] = []
-        for j, i in enumerate(rows):
-            r = pool.requests[i]
-            r.ids, r.dists, r.t_done = pres.ids[j], pres.dists[j], now
-            self.done[r.rid] = r
-            pool.requests[i] = None
-            completed.append(r)
-            if obs.enabled:
-                obs.metrics.observe(
-                    "request_latency_ms", r.latency_ms, kind=plan.kind,
-                    strategy=plan.strategy, tenant=plan.tenant,
-                )
-                obs.metrics.observe("rounds_in_flight", float(rounds[j]),
-                                    kind=plan.kind, strategy=plan.strategy)
+        with obs.tracer.span("post-process"):
+            for j, i in enumerate(rows):
+                r = pool.requests[i]
+                r.ids, r.dists, r.t_done = pres.ids[j], pres.dists[j], now
+                self.done[r.rid] = r
+                pool.requests[i] = None
+                completed.append(r)
+                if obs.enabled:
+                    obs.metrics.observe(
+                        "request_latency_ms", r.latency_ms, kind=plan.kind,
+                        strategy=plan.strategy, tenant=plan.tenant,
+                    )
+                    obs.metrics.observe("rounds_in_flight", float(rounds[j]),
+                                        kind=plan.kind,
+                                        strategy=plan.strategy)
+                if self._slo is not None:
+                    self._slo.record_latency(plan.tenant, r.latency_ms)
+                if obs.convergence is not None:
+                    obs.convergence.finalize_lane(r.rid, int(rounds[j]))
+            if obs.quality is not None:
+                obs.quality.observe(self.searcher, plan, qrows, pres.ids)
             if self._slo is not None:
-                self._slo.record_latency(plan.tenant, r.latency_ms)
-            if obs.convergence is not None:
-                obs.convergence.finalize_lane(r.rid, int(rounds[j]))
-        if obs.quality is not None:
-            obs.quality.observe(self.searcher, plan, qrows, pres.ids)
-        if self._slo is not None:
-            self._stats.slo_violations = self._slo.total_violations
+                self._stats.slo_violations = self._slo.total_violations
         if plan.spec is not None:
-            self._stats.filtered_queries += len(rows)
-        self._stats.retired += len(rows)
-        self._stats.queries += len(rows)
+            self._stats.filtered_queries += n
+        self._stats.retired += n
+        self._stats.queries += n
         if obs.nand_billing:
             with obs.tracer.span("nand-billing"):
                 record_plan_execution(
                     obs.metrics, pres,
                     index=self.mutable if self.mutable is not None
                     else self._index_or_none(),
-                    nand=self.nand, batch_queries=len(rows),
+                    nand=self.nand, batch_queries=n,
                     n_queues=self.nand_queues,
                 )
         return completed
@@ -770,23 +793,28 @@ class ServingEngine:
                                       pool.occupied / len(pool.requests),
                                       kind=pool.session.plan.kind,
                                       strategy=pool.session.plan.strategy)
+            # non-steppable head (tiled/distributed/scan plans, deferred
+            # planning errors): served through the batch-flush path below
+            fallback = bool(self.queue) \
+                and self._session_for(self.queue[0].plan) is None \
+                and (force or self._flush_due())
+            if not fallback and self._watch is not None:
+                with obs.tracer.span("recompile-watch"):
+                    self._watch.sample()
+                    # continuous pools gather-finalize at pow2 buckets up to
+                    # the slot count, so the budget widens to
+                    # max(batch, slots)
+                    width = max(self.batch_size, self.slots)
+                    buckets = int(math.log2(next_pow2(width))) + 1
+                    self._watch.check(
+                        buckets * max(len(self._plan_keys_seen), 1))
         self._stats.ticks += 1
         if obs.enabled:
             obs.metrics.gauge("queue_depth", float(len(self.queue)))
-        # non-steppable head (tiled/distributed/scan plans, deferred
-        # planning errors): serve it through the batch-flush path
-        if self.queue and self._session_for(self.queue[0].plan) is None \
-                and (force or self._flush_due()):
+        if fallback:
             n0 = self._stats.batches
             completed.extend(self._step_batch(force=force))
             self._stats.fallback_batches += self._stats.batches - n0
-        elif self._watch is not None:
-            self._watch.sample()
-            # continuous pools gather-finalize at pow2 buckets up to the
-            # slot count, so the budget widens to max(batch, slots)
-            width = max(self.batch_size, self.slots)
-            buckets = int(math.log2(next_pow2(width))) + 1
-            self._watch.check(buckets * max(len(self._plan_keys_seen), 1))
         if (
             self.auto_consolidate
             and self.mutable is not None

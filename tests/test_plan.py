@@ -305,12 +305,15 @@ def test_engine_stats_derived_from_dataclass(tiny_index):
     eng.drain()
     d = eng.stats
     assert d["batches"] == 1 and d["queries"] == 4
-    # plan-cache counters surface through the dict view (merged from the
-    # planner at read time — they are not EngineStats fields)
+    # plan-cache counters and the device-sync count surface through the
+    # dict view (merged from the planner at read time — they are not
+    # EngineStats fields)
     assert d["plan_cache_misses"] >= 1
     assert d["plan_cache_hits"] >= 3
+    assert d["device_syncs"] >= 1
     assert set(d) == set(EngineStats().as_dict()) | {
-        "plan_cache_hits", "plan_cache_misses"}, "dict view drifted"
+        "plan_cache_hits", "plan_cache_misses",
+        "device_syncs"}, "dict view drifted"
 
 
 def test_validate_attribute_store_shared_helper(tiny_index, tiny_store):
