@@ -11,8 +11,8 @@ from repro.core.segmented import (
 )
 from repro.core.search import (
     Corpus, SearchResult, SearchState, finalize_search, graph_search,
-    graph_search_step, graph_search_stepped, init_search_state, search,
-    search_reference, search_state_active,
+    graph_search_advance, graph_search_step, graph_search_stepped,
+    init_search_state, search, search_reference, search_state_active,
 )
 
 __all__ = [
@@ -37,6 +37,7 @@ __all__ = [
     "SearchState",
     "init_search_state",
     "graph_search_step",
+    "graph_search_advance",
     "graph_search_stepped",
     "finalize_search",
     "search_state_active",

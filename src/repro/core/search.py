@@ -490,7 +490,10 @@ def _finalize_batch(corpus: Corpus, cfg: SearchConfig, metric: str,
 # All three are jit-compiled with fixed shapes (Q lanes x list_size) and built
 # from the same ``_round_fns``/``_finalize_batch`` pieces as ``graph_search``,
 # so iterating the step to quiescence is bit-identical to the while_loop (a
-# vmapped while_loop lowers to exactly this select-guarded step).
+# vmapped while_loop lowers to exactly this select-guarded step).  The
+# scheduler dispatches ``graph_search_advance``: the same step, looped on the
+# device until the first lane quiesces, so the host syncs once per retire
+# point instead of once per round.
 
 
 class SearchState(NamedTuple):
@@ -552,7 +555,14 @@ def graph_search_step(
     ``graph_search`` bit-for-bit."""
     _, cond, body = _round_fns(corpus, cfg, metric, bloom_bits, num_hashes,
                                node_mask)
+    lanes = jax.vmap(_guarded_round(cond, body))(state.queries, state.adts,
+                                                 state.lanes)
+    return state._replace(lanes=lanes)
 
+
+def _guarded_round(cond, body):
+    """One lane's select-guarded round — the iteration a vmapped while_loop
+    lowers to: a lane that ``cond`` finds inactive keeps its state."""
     def step_one(q, adt, s):
         active = cond(s)
         new = body(q, adt, s)
@@ -561,8 +571,54 @@ def graph_search_step(
                 lambda a, b: jnp.where(active, b, a), s, new
             )
 
-    lanes = jax.vmap(step_one)(state.queries, state.adts, state.lanes)
-    return state._replace(lanes=lanes)
+    return step_one
+
+
+@partial(
+    jax.jit,
+    static_argnames=("cfg", "metric", "bloom_bits", "num_hashes"),
+)
+def graph_search_advance(
+    corpus: Corpus,
+    state: SearchState,
+    limit: jnp.ndarray,
+    cfg: SearchConfig,
+    metric: str = "l2",
+    bloom_bits: int = 1 << 17,
+    num_hashes: int = 8,
+    node_mask: jnp.ndarray | None = None,
+) -> tuple[SearchState, jnp.ndarray]:
+    """``graph_search_step`` rounds on the device, in one dispatch, until a
+    lane that was active on entry quiesces, no lane is active, or ``limit``
+    (an int32 scalar, traced: every limit shares one program) rounds have
+    run.  Returns ``(state, packed)``: ``packed`` is an int32 ``(Q + 1,)``
+    array, the active mask after the last round followed by the number of
+    rounds run, so the host reads both in one copy.
+
+    Each round is ``graph_search_step``'s select-guarded round, so every
+    lane follows the trajectory one-round stepping gives it; the stop only
+    decides when the host regains control — on the round the first lane
+    quiesces, which is when a one-round-per-dispatch scheduler would have
+    retired it."""
+    _, cond, body = _round_fns(corpus, cfg, metric, bloom_bits, num_hashes,
+                               node_mask)
+    step_one = _guarded_round(cond, body)
+    active_of = jax.vmap(cond)
+    a0 = active_of(state.lanes)
+
+    def more(carry):
+        lanes, n = carry
+        a = active_of(lanes)
+        return a.any() & ~(a0 & ~a).any() & (n < limit)
+
+    def one_round(carry):
+        lanes, n = carry
+        return jax.vmap(step_one)(state.queries, state.adts, lanes), n + 1
+
+    lanes, n = jax.lax.while_loop(more, one_round,
+                                  (state.lanes, jnp.int32(0)))
+    packed = jnp.append(active_of(lanes).astype(jnp.int32), n)
+    return state._replace(lanes=lanes), packed
 
 
 def search_state_active(state: SearchState, cfg: SearchConfig) -> jnp.ndarray:
@@ -658,6 +714,7 @@ def jit_cache_sizes() -> dict:
         ("graph_search", graph_search),
         ("init_search_state", init_search_state),
         ("graph_search_step", graph_search_step),
+        ("graph_search_advance", graph_search_advance),
         ("finalize_search", finalize_search),
     ):
         if hasattr(fn, "_cache_size"):

@@ -4,9 +4,10 @@ plan layer and the ``core.search`` step kernels.
 A :class:`RoundSession` is the steppable form of one compiled ``QueryPlan``:
 where ``QueryPlanner.execute`` runs the plan's whole traversal inside one
 ``lax.while_loop``, a session exposes the SAME traversal one round at a time
-(``init`` / ``step`` / ``active`` / ``finalize``) so an iteration-level
-scheduler (``ServingEngine(continuous=True)``) can retire finished lanes and
-refill their slots between rounds.  ``complete`` then applies the plan's
+(``init`` / ``step`` / ``active`` / ``finalize``), or up to the next round
+on which a lane quiesces (``advance``), so an iteration-level scheduler
+(``ServingEngine(continuous=True)``) can retire finished lanes and refill
+their slots between rounds.  ``complete`` then applies the plan's
 post-processing (filtered-result wrapping, or the merged path's delta /
 tombstone fusion) to a retired lane batch, producing the same plan-layer
 ``SearchResult`` the batch executor returns — bit-identically, which is what
@@ -76,8 +77,8 @@ class RoundSession:
 
     # ------------------------------------------------------------- stepping
     # Spans (category ``plan``) mark each device program's dispatch —
-    # ``init``, ``round``, ``finalize`` — and each blocking read goes through
-    # the planner's ``DeviceSyncs``.
+    # ``init``, ``round`` (``step`` and ``advance``), ``finalize`` — and each
+    # blocking read goes through the planner's ``DeviceSyncs``.
     def init(self, queries):
         """Round 0 for a (Q, D) batch -> ``core.search.SearchState``."""
         import jax.numpy as jnp
@@ -98,6 +99,23 @@ class RoundSession:
             return graph_search_step(self.corpus, state, self.cfg,
                                      self.metric, self.bloom_bits,
                                      self.num_hashes, self._mask)
+
+    def advance(self, state, limit: int):
+        """Rounds over every lane, in one device dispatch, until a lane that
+        was active quiesces, none is active, or ``limit`` rounds have run
+        -> ``(state, active, rounds_run)``: ``active`` is the (Q,) bool host
+        mask after the last round.  One blocking read, counted under
+        ``active``."""
+        from repro.core.search import graph_search_advance
+
+        tracer = self.planner.obs.tracer
+        with tracer.span("round", cat="plan"):
+            state, packed = graph_search_advance(
+                self.corpus, state, np.int32(limit), self.cfg, self.metric,
+                self.bloom_bits, self.num_hashes, self._mask)
+        with tracer.span("active-sync", cat="plan"):
+            packed = self.planner.syncs.get("active", packed)
+        return state, packed[:-1].astype(bool), int(packed[-1])
 
     def active(self, state) -> np.ndarray:
         """(Q,) bool host array — lanes with rounds still to run."""

@@ -42,18 +42,18 @@ produced negative latencies and spurious/missed flush timeouts.
 
 Continuous batching (``ServingEngine(continuous=True)``): instead of
 flushing whole batches through one ``lax.while_loop``, the engine keeps a
-fixed pool of ``slots`` in-flight lanes per plan cache key and advances ALL
-of them one traversal round per ``step()`` call (a "tick", via the plan
-layer's ``RoundSession`` over the ``core.search`` round-step kernels).
-Lanes whose traversal quiesces are retired immediately — beta rerank,
-delta/tombstone fusion for merged plans, NAND billing, future completion —
-and their slots refill from the queue on the next tick, so no query ever
-waits on another's last round.  Requests are admitted the moment a slot is
-free (no flush window); plans without a round-steppable spine (tiled /
-distributed fan-outs, bitmap scans) fall back to the batch-flush path
-transparently.  Slot pools hold ONE fixed lane shape per plan, so the
-round-step kernels compile once per (plan, slots) — the same pow2-bucket
-recompile budget applies.
+fixed pool of ``slots`` in-flight lanes per plan cache key and, on each
+``step()`` call (a "tick"), advances ALL of them in one device dispatch
+until the first lane quiesces (the plan layer's ``RoundSession.advance``
+over the ``core.search`` round-step kernels).  Lanes whose traversal
+quiesces are retired on that round — beta rerank, delta/tombstone fusion
+for merged plans, NAND billing, future completion — and their slots refill
+from the queue on the next tick, so no query ever waits on another's last
+round.  Requests are admitted the moment a slot is free (no flush window);
+plans without a round-steppable spine (tiled / distributed fan-outs, bitmap
+scans) fall back to the batch-flush path transparently.  Slot pools hold
+ONE fixed lane shape per plan, so the round-step kernels compile once per
+(plan, slots) — the same pow2-bucket recompile budget applies.
 
 Streaming caveats in continuous mode: a lane traverses the base corpus (and,
 when filtered, the admission mask) pinned at its session's creation, while
@@ -123,6 +123,10 @@ class EngineStats:
     filtered_queries: int = 0
     filter_scan_batches: int = 0
     ticks: int = 0                   # continuous mode: round-step ticks run
+    pool_dispatches: int = 0         # continuous mode: advance dispatches
+                                     # (one host read each)
+    pool_rounds: int = 0             # continuous mode: traversal rounds
+                                     # those dispatches ran
     retired: int = 0                 # continuous mode: lanes retired
     fallback_batches: int = 0        # continuous mode: non-steppable plans
                                      # served through the batch-flush path
@@ -282,7 +286,7 @@ class ServingEngine:
             sess0 = self._session_for(plan0)
             if sess0 is not None:
                 z = np.zeros((self.slots, dummy.shape[1]), np.float32)
-                st = sess0.step(sess0.init(z))
+                st, _, _ = sess0.advance(sess0.init(z), 1)
                 sess0.finalize(st)
         # recompile watchdog baselined AFTER warm-up, so only serving-time
         # jit-cache growth is judged against the pow2-bucket x plan budget
@@ -443,10 +447,10 @@ class ServingEngine:
 
         Batch mode: run one plan-homogeneous batch if due (full bucket or
         flush timeout).  Continuous mode: one scheduler tick — admit queued
-        requests into free slots, advance every in-flight lane ONE traversal
-        round, retire lanes that quiesced; plans without a steppable spine
-        flush through the batch path when due.  In streaming mode,
-        consolidation triggers between batches/ticks."""
+        requests into free slots, advance every in-flight lane until the
+        first one quiesces, retire lanes that quiesced; plans without a
+        steppable spine flush through the batch path when due.  In
+        streaming mode, consolidation triggers between batches/ticks."""
         if self.continuous:
             return self._tick(force)
         return self._step_batch(force)
@@ -691,12 +695,18 @@ class ServingEngine:
             self._plan_keys_seen.add(key)
 
     def _step_pool(self, pool: _SlotPool) -> List[Request]:
-        """ONE round over a pool's lanes; finalize + hand back every lane
-        that quiesced.  Retired batches bill through the NAND model exactly
-        like flushed ones (``RoundSession.complete`` returns the same
-        plan-layer result shape)."""
+        """Advance a pool's lanes until the first one quiesces (one device
+        dispatch, one host read); finalize + hand back every lane that
+        quiesced.  Retired batches bill through the NAND model exactly like
+        flushed ones (``RoundSession.complete`` returns the same plan-layer
+        result shape)."""
         obs = self.obs
-        pool.state = pool.session.step(pool.state)
+        # per-round telemetry needs the host after every round
+        limit = 1 if obs.convergence is not None \
+            else pool.session.cfg.max_rounds
+        pool.state, active, ran = pool.session.advance(pool.state, limit)
+        self._stats.pool_dispatches += 1
+        self._stats.pool_rounds += ran
         if obs.convergence is not None:
             # per-round telemetry for every occupied lane — live requests
             # grow the same learned-ET dataset the off-line driver collects
@@ -707,7 +717,6 @@ class ServingEngine:
                         obs.convergence,
                         [pool.requests[i].rid for i in occ],
                         pool.state, select=occ)
-        active = pool.session.active(pool.state)
         rows = [i for i, r in enumerate(pool.requests)
                 if r is not None and not active[i]]
         if not rows:
@@ -777,9 +786,9 @@ class ServingEngine:
 
     def _tick(self, force: bool = False) -> List[Request]:
         """One scheduler tick: refill free slots from the queue, advance
-        every occupied pool one traversal round, retire quiesced lanes.
-        Requests the round-step path cannot serve flush through the batch
-        path when due (or on ``force``)."""
+        every occupied pool until one of its lanes quiesces, retire
+        quiesced lanes.  Requests the round-step path cannot serve flush
+        through the batch path when due (or on ``force``)."""
         obs = self.obs
         completed: List[Request] = []
         with obs.tracer.span("tick"):

@@ -200,3 +200,49 @@ def test_continuous_non_steppable_plan_falls_back(tiny_index):
     for r in rids:
         got = [int(i) for i in eng.done[r].ids if i >= 0]
         assert set(got) <= passing
+
+
+def test_pool_counters_count_rounds_run(tiny_index):
+    """One pool admitted at once runs until its slowest lane quiesces, one
+    dispatch per distinct quiesce round: ``pool_rounds`` is the slowest
+    lane's rounds and ``pool_dispatches`` the number of retire points."""
+    from repro.plan import SearchRequest, Searcher
+
+    q = tiny_index.dataset.queries[:6]
+    eng = ServingEngine(tiny_index, batch_size=8, continuous=True, slots=8)
+    for qq in q:
+        eng.submit(qq)
+    eng.drain()
+    rounds = np.asarray(Searcher.open(tiny_index).search(
+        SearchRequest(queries=q)).raw.rounds)
+    assert eng.stats["pool_rounds"] == int(rounds.max())
+    assert eng.stats["pool_dispatches"] == len(np.unique(rounds))
+    assert eng.stats["ticks"] == eng.stats["pool_dispatches"]
+
+
+def test_convergence_records_match_one_round_path(tiny_index):
+    """With per-round telemetry on, the engine advances one round per
+    dispatch and records exactly what ``trace_session`` (one-round
+    stepping) records for the same lanes."""
+    from repro.obs import ConvergenceLog, Observability, trace_session
+    from repro.plan import SearchRequest, Searcher
+
+    q = tiny_index.dataset.queries[:6]
+    obs = Observability.on(tracing=False, nand_billing=False,
+                           convergence=True)
+    eng = ServingEngine(tiny_index, batch_size=8, continuous=True, slots=8,
+                        obs=obs)
+    for qq in q:
+        eng.submit(qq)
+    eng.drain()
+    assert eng.stats["pool_rounds"] == eng.stats["pool_dispatches"] > 1
+
+    s = Searcher.open(tiny_index)
+    log = ConvergenceLog()
+    trace_session(s.round_session(s.plan(SearchRequest(queries=q[:1]))), q,
+                  log)
+    got, want = obs.convergence.to_arrays(), log.to_arrays()
+    assert got.keys() == want.keys()
+    for f in want:
+        np.testing.assert_array_equal(got[f], want[f], err_msg=f)
+    assert obs.convergence.labels == log.labels

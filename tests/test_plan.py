@@ -305,6 +305,7 @@ def test_engine_stats_derived_from_dataclass(tiny_index):
     eng.drain()
     d = eng.stats
     assert d["batches"] == 1 and d["queries"] == 4
+    assert d["pool_dispatches"] == 0 == d["pool_rounds"]   # batch mode
     # plan-cache counters and the device-sync count surface through the
     # dict view (merged from the planner at read time — they are not
     # EngineStats fields)
@@ -566,3 +567,96 @@ def test_step_is_noop_on_quiesced_lanes(tiny_index):
     for a, b in zip(jax.tree_util.tree_leaves(state),
                     jax.tree_util.tree_leaves(again)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ---------------------------------------------------------------------------
+# Advance: rounds on the device until a lane quiesces, one host read
+# ---------------------------------------------------------------------------
+
+def _session_of(idx, store, kind):
+    """(RoundSession, queries) for a flat, masked or merged plan."""
+    q = idx.dataset.queries[:8]
+    if kind == "merged":
+        from repro.stream import MutableIndex
+
+        mut = MutableIndex(idx)
+        mut.insert(np.asarray(q[0]) + 1e-4)
+        mut.delete(3)
+        s = Searcher.open(mut)
+        spec = None
+    else:
+        s = Searcher.open(idx, attributes=store)
+        spec = SPEC_MODERATE if kind == "masked" else None
+    sess = s.planner.round_session(
+        s.plan(SearchRequest(queries=q[:1], filter=spec)))
+    assert sess is not None and sess.plan.kind == (
+        "merged" if kind == "merged" else "flat")
+    return sess, q
+
+
+@pytest.mark.parametrize("limit", [1, 3])
+def test_advance_with_limit_equals_that_many_steps(tiny_index, tiny_store,
+                                                   limit):
+    """``advance`` bounded by ``limit`` (no lane quiesces that early) is
+    ``limit`` rounds of ``step``, bit for bit, and hands back the same
+    activity mask."""
+    import jax
+
+    sess, q = _session_of(tiny_index, tiny_store, "flat")
+    state = sess.init(q)
+    stepped = state
+    for _ in range(limit):
+        stepped = sess.step(stepped)
+    assert sess.active(stepped).all()
+    adv, active, ran = sess.advance(state, limit)
+    assert ran == limit
+    np.testing.assert_array_equal(active, sess.active(stepped))
+    for a, b in zip(jax.tree_util.tree_leaves(stepped),
+                    jax.tree_util.tree_leaves(adv)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("kind", ["flat", "masked", "merged"])
+def test_advance_retires_like_stepping(tiny_index, tiny_store, kind):
+    """The engine's stop rule (advance until an entry-active lane
+    quiesces, retire what quiesced) gives every lane the ids, dists and
+    rounds of one-round stepping: each dispatch stops on the first round
+    a lane reaches its own stepped fixpoint, so no lane runs past it."""
+    from repro.serve.engine import _gather_rows
+
+    sess, q = _session_of(tiny_index, tiny_store, kind)
+    max_rounds = sess.cfg.max_rounds
+
+    state = sess.init(q)
+    guard = max_rounds + 2
+    while sess.active(state).any():
+        state = sess.step(state)
+        guard -= 1
+        assert guard > 0
+    fix = sess.rounds(state)
+    ref = sess.complete(q, sess.finalize(state))
+
+    state = sess.init(q)
+    active = sess.active(state)
+    got_ids = np.full_like(np.asarray(ref.ids), -7)
+    got_dists = np.full_like(np.asarray(ref.dists), np.nan)
+    got_rounds = np.full_like(fix, -1)
+    dispatches = 0
+    while active.any():
+        before = sess.rounds(state)
+        state, now, ran = sess.advance(state, max_rounds)
+        dispatches += 1
+        assert ran == int((fix - before)[active].min())
+        rows = np.flatnonzero(active & ~now)
+        assert rows.size                       # stopped on a quiesce
+        np.testing.assert_array_equal(fix[rows], before[rows] + ran)
+        core = sess.finalize(_gather_rows(state, rows))
+        res = sess.complete(q[rows], core)
+        got_ids[rows] = np.asarray(res.ids)
+        got_dists[rows] = np.asarray(res.dists)
+        got_rounds[rows] = sess.rounds(state)[rows]
+        active = now
+    np.testing.assert_array_equal(got_ids, np.asarray(ref.ids))
+    np.testing.assert_array_equal(got_dists, np.asarray(ref.dists))
+    np.testing.assert_array_equal(got_rounds, fix)
+    assert dispatches == len(np.unique(fix)) < int(fix.max())

@@ -133,6 +133,26 @@ def test_graph_search_compiles_for_v5e(use_pallas, one_chip,
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
 
 
+def test_graph_search_advance_compiles_for_v5e(one_chip, no_compile_cache):
+    """The continuous scheduler's dispatch: rounds looped on the device,
+    the slot pool's state in and out, one packed (Q + 1,) int32 read."""
+    from repro.core.search import graph_search_advance, init_search_state
+
+    sds = _sds(one_chip)
+    corpus = _corpus_shapes(sds)
+    state = jax.eval_shape(lambda q: init_search_state(corpus, q, CFG, "l2"),
+                           jax.ShapeDtypeStruct((Q, D), jnp.float32))
+    state = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), state)
+    compiled = graph_search_advance.lower(corpus, state,
+                                          sds((), jnp.int32), CFG,
+                                          "l2").compile()
+    assert "while" in compiled.as_text()
+    out = compiled.out_info[1]
+    assert out.shape == (Q + 1,) and out.dtype == jnp.int32
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
 def test_distributed_search_compiles_on_4_chip_mesh(topo, no_compile_cache):
     from repro.core.distributed import ShardedCorpus, distributed_search_kernel
 

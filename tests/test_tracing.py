@@ -150,7 +150,7 @@ def test_continuous_engine_span_tree(tiny_index):
     ticks = _spans(tr, "tick")
     assert ticks
     tick_names = ("refill", "admit", "init", "select-lanes", "quiet-lanes",
-                  "round", "active-sync", "dispatch", "device-wait",
+                  "round", "active-sync", "device-wait",
                   "fetch", "retire", "gather-rows", "finalize", "complete",
                   "post-process", "recompile-watch")
     seen = set()
@@ -166,9 +166,10 @@ def test_continuous_engine_span_tree(tiny_index):
             "post-process"}
     for a in _spans(tr, "admit"):
         assert _children(tr, a, tick_names) >= {"init", "quiet-lanes"}
+    # the activity mask comes back with the advance dispatch (``round``):
+    # its read launches nothing of its own
     for a in _spans(tr, "active-sync"):
-        assert _children(tr, a, tick_names) == {"dispatch", "device-wait",
-                                                "fetch"}
+        assert _children(tr, a, tick_names) == {"device-wait", "fetch"}
 
 
 # ---------------------------------------------------------------------------
@@ -204,19 +205,20 @@ def test_device_syncs_continuous_scheduler(tiny_index):
     base = eng.stats["device_syncs"]
     obs.metrics.clear()
     obs.tracer.clear()
-    t0 = eng.stats["ticks"]
+    d0, r0 = eng.stats["pool_dispatches"], eng.stats["pool_rounds"]
     for q in tiny_index.dataset.queries[:6]:
         eng.submit(q)
     eng.drain()
-    ticks = eng.stats["ticks"] - t0
+    dispatches = eng.stats["pool_dispatches"] - d0
     retires = len(_spans(obs.tracer, "retire"))
-    # one active pull per tick, one core fetch and one rounds pull per
-    # tick that retires lanes; nothing else blocks
-    assert _syncs(obs) == {"site=active": float(ticks),
+    # one active pull per advance dispatch, one core fetch and one rounds
+    # pull per dispatch that retires lanes; nothing else blocks
+    assert _syncs(obs) == {"site=active": float(dispatches),
                            "site=retire": float(retires),
                            "site=rounds": float(retires)}
     assert retires >= 2                        # 6 queries through 4 slots
-    assert eng.stats["device_syncs"] - base == ticks + 2 * retires
+    assert eng.stats["pool_rounds"] - r0 > dispatches   # rounds batched
+    assert eng.stats["device_syncs"] - base == dispatches + 2 * retires
     assert {k.split("=")[1] for k in _syncs(obs)} <= SYNC_SITES
 
 
@@ -245,7 +247,8 @@ def _op_names(compiled_text: str) -> str:
     return "\n".join(re.findall(r'op_name="([^"]*)"', compiled_text))
 
 
-@pytest.mark.parametrize("program", ["graph_search", "graph_search_step"])
+@pytest.mark.parametrize("program", ["graph_search", "graph_search_step",
+                                     "graph_search_advance"])
 def test_traversal_programs_carry_named_scopes(tiny_index, program):
     import jax.numpy as jnp
 
@@ -258,7 +261,9 @@ def test_traversal_programs_carry_named_scopes(tiny_index, program):
         want = BATCH_SCOPES
     else:
         st = search_mod.init_search_state(corpus, q, cfg, metric)
-        low = search_mod.graph_search_step.lower(corpus, st, cfg, metric)
+        args = (np.int32(1),) if program == "graph_search_advance" else ()
+        low = getattr(search_mod, program).lower(corpus, st, *args, cfg,
+                                                 metric)
         want = ROUND_SCOPES
     names = _op_names(low.compile().as_text())
     for scope in want:
