@@ -24,12 +24,17 @@ def _conditioned_starts(rng, n: int, span: float) -> np.ndarray:
     return np.cumsum(gaps)[:n] / gaps.sum() * span
 
 
+def count(rate_qps: float, seconds: float) -> int:
+    """How many requests a window of ``seconds`` at ``rate_qps`` offers."""
+    return max(int(round(rate_qps * seconds)), 1)
+
+
 def window_arrivals(traffic: dict, rate_qps: float, seconds: float,
                     rng: np.random.Generator) -> np.ndarray:
     """The schedule of one run: ``round(rate_qps * seconds)`` requests in
     ``[0, seconds)`` following ``traffic["arrivals"]`` (``poisson`` or
     ``burst`` with ``burst_size`` and ``spread``)."""
-    n = max(int(round(rate_qps * seconds)), 1)
+    n = count(rate_qps, seconds)
     kind = traffic["arrivals"]
     if kind == "poisson":
         return _conditioned_starts(rng, n, seconds)

@@ -1,6 +1,18 @@
 """Finds the benchmark's parts by the names in ``BENCHMARK.json``.
 
 * a configuration: the ``file`` its entry names (``bench/configs/*.json``);
+  its sizes, generator (``bench/corpus.py``), index, search settings and
+  limits. It may add a ``labels`` block (``vocabulary``,
+  ``tags_per_vector``, ``zipf_exponent``, ``cluster_tags``,
+  ``cluster_share``, ``query_tag_shares``, ``min_matches``; see
+  ``corpus.make_labels``): each base vector then carries a tag set and each
+  request a predicate. Such a configuration names an ``adapter`` in its
+  file, a module under ``bench/`` with ``attach(index, offsets, tags,
+  to_corpus)``, which hands the built index the tag sets (``to_corpus`` maps
+  the index's row numbers to the corpus's), and ``request_filter(tags)``,
+  which turns a predicate's tags into the hashable value the program's
+  ``submit`` takes as ``filter``. The program's filter interface is used
+  there and nowhere else in the benchmark;
 * a traffic mix: ``bench/traffic/<traffic>.json``;
 * a per-layer metric: ``bench/metrics/<metric>.py``, a module with
   ``read(record) -> float | None``;
@@ -67,14 +79,31 @@ def units(bench: dict) -> dict:
             for m in bench["end_to_end"] + bench["per_layer"]}
 
 
+def _module(path: Path, prefix: str):
+    spec = importlib.util.spec_from_file_location(
+        prefix + "".join(c if c.isalnum() else "_" for c in path.stem), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def reader(name: str, root: Path = ROOT):
     """The ``read`` function of ``bench/metrics/<name>.py``."""
     path = Path(root) / BENCH_DIR / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_').replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(path, "bench_metric_").read
+
+
+def adapter(config: dict, root: Path = ROOT):
+    """The module that a configuration's ``adapter`` names, or None where
+    it names none."""
+    name = config.get("adapter")
+    if name is None:
+        return None
+    path = (Path(root) / name).resolve()
+    if (Path(root) / BENCH_DIR).resolve() not in path.parents:
+        raise ValueError(f"adapter {name!r} of {config['name']!r} is not "
+                         f"a file under {BENCH_DIR}/")
+    return _module(path, "bench_adapter_")
 
 
 def peaks(device_kind: str, root: Path = ROOT) -> dict:

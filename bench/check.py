@@ -7,8 +7,9 @@ that each request got back) are held to four numbers:
 
 * ``unanswered``: requests due in the window that got no answer a minute
   after it closed (limit 0);
-* ``bad_ids``: answers with an id outside the corpus or repeated within one
-  request's list (limit 0);
+* ``bad_ids``: answers with an id outside the corpus, repeated within one
+  request's list, or, where the request carries a predicate, an id whose
+  tag set lacks one of its tags (limit 0);
 * ``dist_gap_max``: the widest gap between a served distance and the
   float64 distance from that request's own query to the id served, over
   every answer, in units of ``|q| |x|`` (the scale of a distance's rounding
@@ -18,6 +19,10 @@ that each request got back) are held to four numbers:
   top-k, held to a floor (``recall_at_10_min``). It catches a traversal
   that finds worse neighbours (fewer rounds, a shorter list, a broken PQ
   lookup), whose answers the exact rerank still gives true distances.
+
+Where the configuration has tags (``bench/corpus.py`` ``make_labels``), the
+reference is the exact top-k among the base rows whose tag set contains
+every tag of the request's predicate.
 
 The corpus is fixed per configuration and every seed offers the same set of
 queries (``bench/run.py``), so the recall of a sound run is the same number
@@ -37,11 +42,67 @@ def _normalize(x: np.ndarray) -> np.ndarray:
     return x / np.maximum(np.linalg.norm(x, axis=-1, keepdims=True), 1e-300)
 
 
+class TagSets:
+    """The base rows' tag sets, row i holding ``tags[offsets[i]:offsets[i +
+    1]]``. A predicate is a row of tags, -1 where it has no more; a base row
+    passes it when its set contains every one of them."""
+
+    def __init__(self, offsets: np.ndarray, tags: np.ndarray):
+        self.offsets = np.asarray(offsets, np.int64)
+        tags = np.asarray(tags, np.int64)
+        self.n = len(self.offsets) - 1
+        rows = np.repeat(np.arange(self.n), np.diff(self.offsets))
+        order = np.argsort(tags, kind="stable")
+        self._by_tag, self._rows = tags[order], rows[order]
+        self._width = int(tags.max(initial=-1)) + 1
+        self._keys = rows * self._width + tags        # (row, tag) pairs
+
+    def rows_with(self, tag: int) -> np.ndarray:
+        """The base rows that have ``tag``, ascending."""
+        a, b = np.searchsorted(self._by_tag, [tag, tag + 1])
+        return self._rows[a:b]
+
+    def count(self, predicate) -> int:
+        """How many base rows pass ``predicate``."""
+        rows = None
+        for t in predicate:
+            if t >= 0:
+                r = self.rows_with(t)
+                rows = r if rows is None else np.intersect1d(
+                    rows, r, assume_unique=True)
+        return self.n if rows is None else len(rows)
+
+    def admitted(self, predicates: np.ndarray) -> np.ndarray:
+        """(R, N) bool: base row j passes predicate r."""
+        out = np.ones((len(predicates), self.n), bool)
+        for r, pred in enumerate(np.asarray(predicates)):
+            for t in pred[pred >= 0]:
+                has = np.zeros(self.n, bool)
+                has[self.rows_with(t)] = True
+                out[r] &= has
+        return out
+
+    def contains(self, ids: np.ndarray, predicates: np.ndarray) -> np.ndarray:
+        """(R, k) bool: base row ``ids[r, j]`` passes ``predicates[r]``;
+        false for an id outside the corpus."""
+        ids = np.asarray(ids, np.int64)
+        ok = (ids >= 0) & (ids < self.n)
+        for tag in np.asarray(predicates, np.int64).T:
+            t = tag[:, None]
+            key = np.where(ok, ids, 0) * self._width + np.clip(
+                t, 0, max(self._width - 1, 0))
+            ok &= (t < 0) | ((t < self._width) & np.isin(key, self._keys))
+        return ok
+
+
 def exact_knn(queries: np.ndarray, base: np.ndarray, k: int, metric: str,
-              chunk: int = 256) -> np.ndarray:
+              chunk: int = 256, tag_sets: TagSets | None = None,
+              predicates: np.ndarray | None = None) -> np.ndarray:
     """(Q, k) ids of the exact nearest neighbours, nearest first, in
     float64. Distances are squared L2 (``l2``) or minus the cosine
-    (``angular``)."""
+    (``angular``). With ``predicates`` (one row per query) only the base
+    rows of ``tag_sets`` that pass a query's predicate are its neighbours;
+    -1 fills a row whose predicate admits fewer than ``k``."""
     b = np.asarray(base, np.float64)
     if metric == "angular":
         b = _normalize(b)
@@ -55,10 +116,17 @@ def exact_knn(queries: np.ndarray, base: np.ndarray, k: int, metric: str,
             d = b2[None, :] - 2.0 * (q @ b.T)    # + |q|^2, same for a row
         else:
             raise ValueError(f"unknown metric {metric!r}")
+        if predicates is not None:
+            d = np.where(tag_sets.admitted(predicates[s:s + chunk]), d,
+                         np.inf)
         idx = np.argpartition(d, k, axis=1)[:, :k]
         row = np.take_along_axis(d, idx, axis=1)
-        out[s:s + chunk] = np.take_along_axis(
-            idx, np.argsort(row, axis=1, kind="stable"), axis=1)
+        order = np.argsort(row, axis=1, kind="stable")
+        ids = np.take_along_axis(idx, order, axis=1)
+        if predicates is not None:
+            ids = np.where(np.isinf(np.take_along_axis(row, order, axis=1)),
+                           -1, ids)
+        out[s:s + chunk] = ids
     return out
 
 
@@ -82,29 +150,41 @@ def distance_gaps(queries: np.ndarray, base: np.ndarray, ids: np.ndarray,
     return np.where(valid, gap, np.inf)
 
 
-def bad_id_rows(ids: np.ndarray, n: int) -> np.ndarray:
-    """(Q,) bool: a row holds an id outside ``[0, n)`` or an id twice."""
+def bad_id_rows(ids: np.ndarray, n: int,
+                passes: np.ndarray | None = None) -> np.ndarray:
+    """(Q,) bool: a row holds an id outside ``[0, n)``, an id twice, or,
+    given ``passes`` (Q, k), an id its request's predicate refuses."""
     ids = np.asarray(ids, np.int64)
     outside = ((ids < 0) | (ids >= n)).any(axis=1)
     s = np.sort(ids, axis=1)
     repeated = (s[:, 1:] == s[:, :-1]).any(axis=1)
-    return outside | repeated
+    bad = outside | repeated
+    if passes is not None:
+        bad |= ~np.asarray(passes, bool).all(axis=1)
+    return bad
 
 
 def compare(queries: np.ndarray, base: np.ndarray, ids: np.ndarray,
             dists: np.ndarray, answered: np.ndarray, metric: str,
-            truth: np.ndarray | None = None) -> dict:
+            truth: np.ndarray | None = None,
+            tag_sets: TagSets | None = None,
+            predicates: np.ndarray | None = None) -> dict:
     """The readings of one run. ``queries`` (R, dim) are the requests due in
     the window, ``ids``/``dists`` (R, k) their answers in the corpus's own
     id space, ``answered`` (R,) bool, ``truth`` (R, k) their exact top-k
-    (computed here when not given). Returns the four numbers and the
+    (computed here when not given), ``predicates`` (R, T) their predicates
+    over ``tag_sets``, if they carry any. Returns the four numbers and the
     per-request failure masks (before limits)."""
     answered = np.asarray(answered, bool)
     got = np.flatnonzero(answered)
     k = np.asarray(ids).shape[1]
     if truth is None:
-        truth = exact_knn(queries, base, k, metric)
-    bad = bad_id_rows(ids[got], len(base))
+        truth = exact_knn(queries, base, k, metric, tag_sets=tag_sets,
+                          predicates=predicates)
+    passes = None
+    if predicates is not None:
+        passes = tag_sets.contains(ids[got], np.asarray(predicates)[got])
+    bad = bad_id_rows(ids[got], len(base), passes)
     gaps = distance_gaps(queries[got], base, ids[got], dists[got], metric)
     row_gap = gaps.max(axis=1) if len(got) else np.zeros(0)
     row_recall = row_recalls(ids[got], truth[got], k)

@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Callable, List, Optional
+from typing import Callable, List, Optional, Sequence
 
 import numpy as np
 
@@ -68,11 +68,13 @@ MIN_SPAN_S = 50e-6
 def drive(engine, queries: np.ndarray, due: np.ndarray, seconds: float,
           k: int, grace: float = 60.0,
           spans: Optional[List[tuple]] = None,
-          on_step: Optional[Callable[[list], None]] = None) -> ClientLog:
-    """Run one window: request ``i`` (query ``queries[i]``) is due at
-    ``due[i]`` seconds after the start. After the window closes the loop
-    keeps stepping, unforced, until every request is answered or ``grace``
-    seconds have passed.
+          on_step: Optional[Callable[[list], None]] = None,
+          filters: Optional[Sequence] = None) -> ClientLog:
+    """Run one window: request ``i`` (query ``queries[i]``, and filter
+    ``filters[i]`` where filters are given) is due at ``due[i]`` seconds
+    after the start. After the window closes the loop keeps stepping,
+    unforced, until every request is answered or ``grace`` seconds have
+    passed.
 
     ``spans``, if given, receives ``(name, start, end)`` in window seconds
     for every submit, sleep and step that did work (``bench.submit``,
@@ -93,7 +95,11 @@ def drive(engine, queries: np.ndarray, due: np.ndarray, seconds: float,
         now = clock()
         if i < n and due[i] <= now - t0:
             while i < n and due[i] <= clock() - t0:
-                row_of[engine.submit(queries[i])] = i
+                if filters is None:
+                    rid = engine.submit(queries[i])
+                else:
+                    rid = engine.submit(queries[i], filter=filters[i])
+                row_of[rid] = i
                 submitted[i] = clock() - t0
                 i += 1
             if log_span is not None:

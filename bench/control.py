@@ -8,13 +8,21 @@ Two controls have to come out as not correct:
   control stores the corpus and the queries in bfloat16 (the step that would
   tempt a later change: half the bytes per raw vector) and runs exact search
   in float32 arithmetic over the rounded values on the device. It fails
-  ``dist_gap_max``.
+  ``dist_gap_max``. Vectors of whole numbers 0..255 (``assumed.quantize``
+  ``uint8``) are exact in bfloat16, so for them the control keeps 4 bits of
+  each value (``int4``).
 * the traversal fault: the program itself with its traversal capped at
   ``FAULT_ROUNDS`` rounds (a sound query takes about twice as many), served
   through the same engine, scheduler and traffic. Its answers carry exact
   distances (the rerank is intact), so only the recall floor can fail it.
   (Halving the search list instead moves recall by under 0.002: early
   termination, not the list, ends these searches.)
+* the filter fault, for a configuration with ``labels``: the program's
+  engine serving the same requests without their filters. Its answers
+  break their predicates, which ``bad_ids`` counts.
+
+With labels every control keeps the requests' predicates: the precision
+control searches only the rows each predicate admits.
 
     python bench/control.py --workload <cell> --seconds <s>[,<s>...] \\
         [--program-seeds a,b,...] [--control-seeds x,y,z] [--rate <q/s>] \
@@ -22,7 +30,7 @@ Two controls have to come out as not correct:
 
 builds the cell's index once, then drives one window of the cell's traffic
 per program seed and length through the program's engine, and one per
-control seed through the fault's engine, and runs the precision control on
+control seed through each fault's engine, and runs the precision control on
 the requests of each control seed (at ``--control-seconds``, by default
 the first length). Each prints one JSON line: the readings
 against the limits, and the window's latency and throughput. ``--rate``
@@ -42,33 +50,51 @@ sys.path.insert(0, str(ROOT))
 
 import numpy as np  # noqa: E402
 
-from bench import catalog, check, client, corpus  # noqa: E402
+from bench import arrivals, catalog, check, client, corpus  # noqa: E402
 
 # the traversal fault's cap on rounds
 FAULT_ROUNDS = 16
 
 
+def lower_precision(config: dict) -> str:
+    """The precision the control stores a configuration's vectors in."""
+    return "int4" if config["assumed"].get("quantize") == "uint8" \
+        else "bfloat16"
+
+
 def control_answers(base: np.ndarray, queries: np.ndarray, k: int,
-                    metric: str, chunk: int = 512):
-    """(ids, dists) of exact top-k search over bfloat16-stored vectors,
-    computed in float32 on the default JAX device."""
+                    metric: str, chunk: int = 512,
+                    tag_sets: check.TagSets | None = None,
+                    predicates: np.ndarray | None = None,
+                    precision: str = "bfloat16"):
+    """(ids, dists) of exact top-k search over vectors stored in
+    ``precision`` (``bfloat16``, or ``int4``: a value 0..255 kept as the
+    middle of its 16-wide step), computed in float32 on the default JAX
+    device; with ``predicates``, over the base rows of ``tag_sets`` that
+    each query's predicate admits."""
     import jax
     import jax.numpy as jnp
 
     def stored(x):
         x = jnp.asarray(x, jnp.float32)
+        if precision == "int4":
+            x = jnp.floor(x / 16.0) * 16.0 + 8.0
         if metric == "angular":
             x = x / jnp.maximum(jnp.linalg.norm(x, axis=-1, keepdims=True),
                                 1e-12)
-        return x.astype(jnp.bfloat16).astype(jnp.float32)
+        if precision == "bfloat16":
+            x = x.astype(jnp.bfloat16).astype(jnp.float32)
+        return x
 
     @jax.jit
-    def top(q, b):
+    def top(q, b, admitted):
         if metric == "angular":
             d = -jnp.matmul(q, b.T, precision="highest")
         else:
             d = (jnp.sum(b * b, -1)[None, :]
                  - 2.0 * jnp.matmul(q, b.T, precision="highest"))
+        if admitted is not None:
+            d = jnp.where(admitted, d, jnp.inf)
         _, ids = jax.lax.top_k(-d, k)
         x = b[ids]                                       # (Q, k, D)
         if metric == "angular":
@@ -80,18 +106,21 @@ def control_answers(base: np.ndarray, queries: np.ndarray, k: int,
     b = stored(base)
     ids, dists = [], []
     for s in range(0, len(queries), chunk):
-        i, d = top(stored(queries[s:s + chunk]), b)
+        admitted = None if predicates is None else tag_sets.admitted(
+            predicates[s:s + chunk])
+        i, d = top(stored(queries[s:s + chunk]), b, admitted)
         ids.append(np.asarray(i))
         dists.append(np.asarray(d))
     return np.concatenate(ids).astype(np.int64), np.concatenate(dists)
 
 
-def readings(config: dict, base, queries, ids, dists,
-             answered=None) -> dict:
+def readings(config: dict, base, queries, ids, dists, answered=None,
+             tag_sets=None, predicates=None) -> dict:
     """The comparison of a run, over these answers."""
     if answered is None:
         answered = np.ones(len(queries), bool)
-    r = check.compare(queries, base, ids, dists, answered, config["metric"])
+    r = check.compare(queries, base, ids, dists, answered, config["metric"],
+                      tag_sets=tag_sets, predicates=predicates)
     correct, failed, shown = check.verdict(r, config["limits"])
     return {"correct": correct, "failed": failed, "checks": shown}
 
@@ -102,18 +131,21 @@ def served_ids(log: client.ClientLog, to_corpus: np.ndarray) -> np.ndarray:
     return np.where(log.answered[:, None], ids, -1)
 
 
-def window(engine, config, traffic, rate, seconds, pool, base, to_corpus,
-           seed) -> dict:
-    """One window of the cell's traffic through ``engine``: its readings
-    and its latency and throughput."""
-    from bench.run import schedule
+def window(engine, config, traffic, rate, seconds, data, to_corpus,
+           seed, drop_filters: bool = False) -> dict:
+    """One window of the cell's traffic through ``engine``, each request
+    with its filter unless ``drop_filters``: its readings and its latency
+    and throughput."""
+    from bench.run import window_rows
 
     k = int(config["k"])
-    due, queries = schedule(traffic, rate, seconds, pool, seed)
-    log = client.drive(engine, queries, due, seconds, k)
+    due, rows = window_rows(traffic, rate, seconds, len(data.pool), seed)
+    queries, filters, predicates = data.requests(rows)
+    log = client.drive(engine, queries, due, seconds, k,
+                       filters=None if drop_filters else filters)
     engine.done.clear()
-    out = readings(config, base, queries, served_ids(log, to_corpus),
-                   log.dists, log.answered)
+    out = readings(config, data.base, queries, served_ids(log, to_corpus),
+                   log.dists, log.answered, data.tag_sets, predicates)
     lat = log.latency_ms
     out.update(requests=len(due), qps=client.qps(log),
                **{f"p{q}_ms": client.percentile(lat, q)
@@ -150,8 +182,12 @@ def main(argv=None) -> int:
     rate = args.rate or float(traffic["load"]) * float(traffic["knee_qps"])
     lengths = [float(s) for s in args.seconds.split(",")]
     control_lengths = [args.control_seconds or lengths[0]]
-    base, pool = corpus.make_corpus(config)
-    index, to_corpus = system.build(config, base, corpus.build_seed(config))
+    data = run.load_data(config)
+    index, to_corpus = system.build(config, data.base,
+                                    corpus.build_seed(config))
+    data.attach(index, to_corpus)
+    requests = max(arrivals.count(rate, s)
+                   for s in lengths + control_lengths)
     device = {"platform": dev.platform, "kind": dev.device_kind}
 
     def emit(kind, seed, seconds, out):
@@ -162,26 +198,36 @@ def main(argv=None) -> int:
     runs = [("program", None, _seeds(args.program_seeds), lengths),
             ("rounds_cut", {"max_rounds": FAULT_ROUNDS},
              _seeds(args.control_seeds), control_lengths)]
+    if data.labels is not None:
+        runs.append(("filter_dropped", None, _seeds(args.control_seeds),
+                     control_lengths))
     for kind, changes, seeds, seconds_list in runs:
         if not seeds:
             continue
         engine = system.open_engine(index, traffic, metrics=False,
                                     search_changes=changes)
-        system.warm_up(engine, pool)
+        run.warm_up(engine, data, requests)
         for seed in seeds:
             for seconds in seconds_list:
                 emit(kind, seed, seconds,
-                     window(engine, config, traffic, rate, seconds, pool,
-                            base, to_corpus, seed))
+                     window(engine, config, traffic, rate, seconds, data,
+                            to_corpus, seed,
+                            drop_filters=kind == "filter_dropped"))
         del engine
     for seed in _seeds(args.control_seeds):
         for seconds in control_lengths:
-            queries = run.schedule(traffic, rate, seconds, pool, seed)[1]
-            ids, dists = control_answers(base, queries, int(config["k"]),
-                                         config["metric"])
-            out = readings(config, base, queries, ids, dists)
+            rows = run.window_rows(traffic, rate, seconds, len(data.pool),
+                                   seed)[1]
+            queries, _, predicates = data.requests(rows)
+            precision = lower_precision(config)
+            ids, dists = control_answers(
+                data.base, queries, int(config["k"]), config["metric"],
+                tag_sets=data.tag_sets, predicates=predicates,
+                precision=precision)
+            out = readings(config, data.base, queries, ids, dists,
+                           tag_sets=data.tag_sets, predicates=predicates)
             out["requests"] = len(queries)
-            emit("bfloat16", seed, seconds, out)
+            emit(precision, seed, seconds, out)
     return 0
 
 

@@ -5,13 +5,16 @@
 The cell (``BENCHMARK.json`` ``workloads``) names a configuration and a
 traffic mix. One run, in one process:
 
-1. generates the configuration's corpus and query pool, and draws the
-   window's traffic from ``--seed`` (``bench/corpus.py``);
-2. builds the index with the program's ``build_index``, on the host;
+1. generates the configuration's corpus and query pool (with its tags and
+   predicates, where it has ``labels``), and draws the window's traffic
+   from ``--seed`` (``bench/corpus.py``);
+2. builds the index with the program's ``build_index``, on the host, and
+   hands it the tag sets through the configuration's adapter;
 3. opens ``ServingEngine`` with the mix's scheduler and warms up every shape
    the mix can use (``bench/system.py``);
 4. offers ``round(rate * seconds)`` requests open-loop over ``--seconds``
-   (``bench/arrivals.py``, ``bench/client.py``), then waits for the
+   (``bench/arrivals.py``, ``bench/client.py``), each with the filter of
+   its pool row where the configuration has labels, then waits for the
    stragglers;
 5. compares every answer with the float64 brute-force reference
    (``bench/check.py``) and prints the result as one JSON line.
@@ -41,6 +44,7 @@ import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 from pathlib import Path  # noqa: E402
+from typing import Any, NamedTuple, Optional  # noqa: E402
 
 ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
@@ -89,6 +93,55 @@ def require_chips(chips: int) -> list:
     if len(devices) < chips:
         raise NoChip(f"{len(devices)} chips, the cell needs {chips}")
     return devices
+
+
+class Data(NamedTuple):
+    """A configuration's data: its corpus and query pool and, where it has
+    ``labels``, their tags, the tag sets as the reference reads them, the
+    adapter, and each pool row's filter as the program takes it."""
+    base: np.ndarray
+    pool: np.ndarray
+    labels: Optional[corpus.Labels] = None
+    tag_sets: Optional[check.TagSets] = None
+    adapter: Any = None
+    filters: Optional[list] = None
+
+    def requests(self, rows: np.ndarray):
+        """(queries, filters or None, predicates or None) of requests that
+        carry pool rows ``rows``."""
+        if self.labels is None:
+            return self.pool[rows], None, None
+        return (self.pool[rows], [self.filters[r] for r in rows],
+                self.labels.predicates[rows])
+
+    def attach(self, index, to_corpus: np.ndarray) -> None:
+        """Hand the built index the base rows' tag sets."""
+        if self.adapter is not None:
+            self.adapter.attach(index, self.labels.offsets, self.labels.tags,
+                                to_corpus)
+
+
+def load_data(config: dict, root: Path = ROOT) -> Data:
+    """The configuration's data, generated from its ``data_seed``."""
+    base, pool = corpus.make_corpus(config)
+    if "labels" not in config:
+        return Data(base, pool)
+    adapter = catalog.adapter(config, root)
+    if adapter is None:
+        raise ValueError(f"configuration {config['name']!r} has labels and "
+                         "names no adapter")
+    labels = corpus.make_labels(config)
+    filters = [adapter.request_filter(tuple(int(t) for t in p[p >= 0]))
+               for p in labels.predicates]
+    return Data(base, pool, labels, check.TagSets(labels.offsets,
+                                                  labels.tags),
+                adapter, filters)
+
+
+def row_uses(requests: int, pool: int) -> np.ndarray:
+    """(pool,) how many of a window's ``requests`` carry each pool row
+    (``query_order``)."""
+    return np.bincount(np.arange(requests) % pool, minlength=pool)
 
 
 def query_order(rng, n: int, pool: int) -> np.ndarray:
@@ -194,24 +247,28 @@ def run(args, root: Path = ROOT, on_chip: bool = True,
     config = catalog.config(bench, cell["config"], root)
     traffic = catalog.traffic(cell["traffic"], root)
     seed, seconds, k = args.seed, float(args.seconds), int(config["k"])
+    rate = float(traffic["load"]) * float(traffic["knee_qps"])
 
-    base, pool = corpus.make_corpus(config)
+    data = load_data(config, root)
     t_build = time.perf_counter()
-    index, to_corpus = system.build(config, base, corpus.build_seed(config))
+    index, to_corpus = system.build(config, data.base,
+                                    corpus.build_seed(config))
+    data.attach(index, to_corpus)
     t_engine = time.perf_counter()
     engine = system.open_engine(index, traffic, metrics=bool(args.trace))
-    system.warm_up(engine, pool)
+    windows = (seconds, TRACE_SECONDS) if args.trace else (seconds,)
+    warm_up(engine, data, max(arrivals.count(rate, s) for s in windows))
     t_warm = time.perf_counter()
 
-    rate = float(traffic["load"]) * float(traffic["knee_qps"])
-    due, queries = schedule(traffic, rate, seconds, pool, seed)
+    due, rows = window_rows(traffic, rate, seconds, len(data.pool), seed)
+    queries, filters, _ = data.requests(rows)
 
     stats0 = dict(engine.stats)
     compiles = CompileCounter()
     pauses = GcPauses() if args.trace else None
     t_open = time.perf_counter()
     setup_s = t_open - _T0
-    log = client.drive(engine, queries, due, seconds, k)
+    log = client.drive(engine, queries, due, seconds, k, filters=filters)
     in_window = compiles.between(log.t0, log.t0 + seconds)
     if pauses is not None:
         pauses.close()
@@ -219,9 +276,9 @@ def run(args, root: Path = ROOT, on_chip: bool = True,
     queue_wait = None
     if engine.obs.metrics.enabled:
         queue_wait = engine.obs.metrics.merged_histogram("queue_wait_ms")
-    logs = [(queries, log)]
+    logs = [(rows, log)]
     if args.trace:
-        traced = traced_window(engine, traffic, rate, pool, seed, k)
+        traced = traced_window(engine, traffic, rate, data, seed, k)
         logs.append(traced[:2])
         counters, reduced = traced[2:]
     if in_window:
@@ -231,15 +288,18 @@ def run(args, root: Path = ROOT, on_chip: bool = True,
     gc.collect()
 
     # ---- reference, once the windows have closed and the engine is gone
-    queries = np.concatenate([q for q, _ in logs])
+    queries, _, predicates = data.requests(
+        np.concatenate([r for r, _ in logs]))
     answered = np.concatenate([lg.answered for _, lg in logs])
     ids = np.concatenate([lg.ids for _, lg in logs])
     dists = np.concatenate([lg.dists for _, lg in logs])
     served = np.where(ids >= 0, to_corpus[np.maximum(ids, 0)], -1)
     served = np.where(answered[:, None], served, -1)
-    truth = check.exact_knn(queries, base, k, config["metric"])
-    readings = check.compare(queries, base, served, dists, answered,
-                             config["metric"], truth)
+    truth = check.exact_knn(queries, data.base, k, config["metric"],
+                            tag_sets=data.tag_sets, predicates=predicates)
+    readings = check.compare(queries, data.base, served, dists, answered,
+                             config["metric"], truth, data.tag_sets,
+                             predicates)
     correct, failed, shown = check.verdict(readings, config["limits"])
     got = np.flatnonzero(answered[:len(log.due)])
     recall = check.recall_at_k(served[got], truth[got], k)
@@ -276,6 +336,11 @@ def run(args, root: Path = ROOT, on_chip: bool = True,
           f"{rate:.4f}/s over {seconds} s, "
           f"{stats['batches']} batches, {stats['ticks']} ticks, "
           f"{len(in_window)} compiles in the window", file=err)
+    if data.labels is not None:
+        print(f"# filters: {stats['filtered_queries']} filtered queries, "
+              f"{stats['filter_scan_batches']} scan batches, "
+              f"{stats['batches'] / max(stats['queries'], 1):.4f} batches "
+              f"a query", file=err)
     lat = log.latency_ms
     print("# window: " + ", ".join(
         f"p{q} {client.percentile(lat, q):.3f} ms" for q in (50, 90, 99))
@@ -287,26 +352,45 @@ def run(args, root: Path = ROOT, on_chip: bool = True,
     return result
 
 
-def schedule(traffic: dict, rate: float, seconds: float, pool, seed: int,
-             traced: bool = False):
-    """(due times, queries) of the measured window, or of the traced one."""
+def window_rows(traffic: dict, rate: float, seconds: float, pool: int,
+                seed: int, traced: bool = False):
+    """(due times, pool rows) of the measured window, or of the traced
+    one, over a pool of ``pool`` queries."""
     a, o = (corpus.STREAM_TRACE_ARRIVALS, corpus.STREAM_TRACE_ORDER) \
         if traced else (corpus.STREAM_ARRIVALS, corpus.STREAM_ORDER)
     due = arrivals.window_arrivals(traffic, rate, seconds,
                                    corpus.rng_for(seed, a))
-    rows = query_order(corpus.rng_for(seed, o), len(due), len(pool))
+    return due, query_order(corpus.rng_for(seed, o), len(due), pool)
+
+
+def schedule(traffic: dict, rate: float, seconds: float, pool, seed: int,
+             traced: bool = False):
+    """(due times, queries) of the measured window, or of the traced one."""
+    due, rows = window_rows(traffic, rate, seconds, len(pool), seed, traced)
     return due, pool[rows]
 
 
-def traced_window(engine, traffic, rate, pool, seed, k):
+def warm_up(engine, data: Data, requests: int) -> None:
+    """``system.warm_up`` for windows of at most ``requests`` requests."""
+    from bench import system
+
+    if data.labels is None:
+        system.warm_up(engine, data.pool)
+        return
+    system.warm_up(engine, data.pool, data.filters, data.labels.admits,
+                   row_uses(requests, len(data.pool)))
+
+
+def traced_window(engine, traffic, rate, data: Data, seed, k):
     """A further ``TRACE_SECONDS`` of the same traffic under the profiler,
-    after the measured window: (queries, log, counters, reduced trace)."""
+    after the measured window: (pool rows, log, counters, reduced trace)."""
     import jax
 
     from bench import system, trace_reduce
 
-    due, queries = schedule(traffic, rate, TRACE_SECONDS, pool, seed,
-                            traced=True)
+    due, rows = window_rows(traffic, rate, TRACE_SECONDS, len(data.pool),
+                            seed, traced=True)
+    queries, filters, _ = data.requests(rows)
     counters = system.Counters(engine)
     spans: list = []
     t_close = [float("inf")]
@@ -324,7 +408,7 @@ def traced_window(engine, traffic, rate, pool, seed, k):
             anchor = time.perf_counter()
         t_close[0] = time.perf_counter() + TRACE_SECONDS
         log = client.drive(engine, queries, due, TRACE_SECONDS, k,
-                           spans=spans, on_step=on_step)
+                           spans=spans, on_step=on_step, filters=filters)
         jax.profiler.stop_trace()
         t_read = time.perf_counter()
         trace = trace_reduce.from_xplane(trace_dir)
@@ -337,7 +421,7 @@ def traced_window(engine, traffic, rate, pool, seed, k):
     lo = _anchor_ns(trace) + (log.t0 - anchor) * 1e9
     trace["planes"].append(_host_plane(spans,
                                        (lo, lo + TRACE_SECONDS * 1e9)))
-    return queries, log, counters, trace_reduce.reduce_trace(trace)
+    return rows, log, counters, trace_reduce.reduce_trace(trace)
 
 
 def _anchor_ns(trace: dict) -> float:

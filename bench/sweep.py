@@ -28,12 +28,16 @@ import numpy as np  # noqa: E402
 from bench import catalog, check, client, corpus, run  # noqa: E402
 
 
-def window(engine, pool, traffic, rate, seconds, seed, k):
-    due, queries = run.schedule(traffic, rate, seconds, pool, seed)
-    log = client.drive(engine, queries, due, seconds, k, grace=30.0)
+def window(engine, data, traffic, rate, seconds, seed, k):
+    """One window, each request with its pool row's filter, if any:
+    (pool rows, log, requests late at the close)."""
+    due, rows = run.window_rows(traffic, rate, seconds, len(data.pool), seed)
+    queries, filters, _ = data.requests(rows)
+    log = client.drive(engine, queries, due, seconds, k, grace=30.0,
+                       filters=filters)
     engine.done.clear()
     late = int((~log.answered | (log.done > seconds)).sum())
-    return queries, log, late
+    return rows, log, late
 
 
 def sustained(late: int, offered: int, batch: int) -> bool:
@@ -59,20 +63,26 @@ def main(argv=None) -> int:
     config = catalog.config(bench, args.config)
     traffic = catalog.traffic(args.traffic)
     k = int(config["k"])
-    base, pool = corpus.make_corpus(config)
-    index, to_corpus = system.build(config, base, corpus.build_seed(config))
+    data = run.load_data(config)
+    index, to_corpus = system.build(config, data.base,
+                                    corpus.build_seed(config))
+    data.attach(index, to_corpus)
     engine = system.open_engine(index, traffic, metrics=False)
-    system.warm_up(engine, pool)
     batch = int(traffic["batch_size"])
+    # rates are not known before the sweep: warm every bucket
+    run.warm_up(engine, data, batch * len(data.pool))
 
     def trial(rate, i):
-        queries, log, late = window(engine, pool, traffic, rate,
-                                    args.seconds, args.seed + i, k)
+        rows, log, late = window(engine, data, traffic, rate,
+                                 args.seconds, args.seed + i, k)
         ok = sustained(late, len(log.due), batch)
         lat = log.latency_ms
         got = np.flatnonzero(log.answered)[:512]
         served = to_corpus[np.maximum(log.ids[got], 0)]
-        truth = check.exact_knn(queries[got], base, k, config["metric"])
+        queries, _, predicates = data.requests(rows[got])
+        truth = check.exact_knn(queries, data.base, k, config["metric"],
+                                tag_sets=data.tag_sets,
+                                predicates=predicates)
         print(json.dumps({
             "rate": rate, "offered": len(log.due), "qps": client.qps(log),
             "late_at_close": late, "sustained": ok,

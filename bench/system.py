@@ -2,7 +2,9 @@
 build over the benchmark's corpus, its ``ServingEngine``, the warm-up of
 every shape a cell's traffic uses, and the counters a traced run reads.
 
-This is the only module of the benchmark that imports the program.
+This is the only module of the benchmark that imports the program, besides
+the configurations' adapters (``catalog.adapter``): they alone use the
+program's filter interface, so that a configuration can bring its own.
 """
 from __future__ import annotations
 
@@ -103,30 +105,84 @@ def _pow2_upto(n: int):
         b *= 2
 
 
-def warm_up(engine, queries: np.ndarray) -> None:
+def _filter_representatives(filters, admits, uses) -> list:
+    """[(pool row, most requests)]: the pool rows whose filters warm every
+    filtered shape, and the most requests that share one filter in a
+    window. The program's filtered shapes depend on a filter only through
+    how many base rows it admits (the regime, the widened search list, the
+    scan's power-of-two length), and each of those is monotone in it; so
+    the filters that admit the fewest and the most rows within each band
+    of that count (2**(j - 1), 2**j] cover every shape of the band."""
+    groups: dict = {}
+    for row, f in enumerate(filters):
+        groups.setdefault(f, []).append(row)
+    bands: dict = {}
+    for rows in groups.values():
+        most = int(sum(uses[r] for r in rows))
+        if most:
+            n = int(admits[rows[0]])
+            bands.setdefault((n - 1).bit_length(), []).append(
+                (n, rows[0], most))
+    out = []
+    for band in bands.values():
+        most = max(m for _, _, m in band)
+        for row in sorted({min(band)[1], max(band)[1]}):
+            out.append((row, most))
+    return out
+
+
+def warm_up(engine, queries: np.ndarray, filters=None, admits=None,
+            uses=None) -> None:
     """Compile every shape the window can use, through the engine's own
     path: each power-of-two batch bucket up to ``batch_size``; in
     continuous mode also the slot pool's init/step/refill and the retire
     gather + finalize at every power-of-two row count up to ``slots``.
-    Leaves the engine idle with its completed map empty."""
+    Leaves the engine idle with its completed map empty.
+
+    With ``filters`` (each pool row's), ``admits`` (the base rows each
+    admits) and ``uses`` (how many requests of a window carry each row)
+    the window's requests are all filtered: each bucket, or the slot pool,
+    is warmed with the filters of ``_filter_representatives``, and a
+    bucket only up to the most requests that share a filter."""
     from repro.serve import engine as engine_mod
 
+    if filters is None:
+        work = [(None, None, 2 * engine.slots if engine.continuous
+                 else engine.batch_size)]
+    else:
+        work = [(row, filters[row], most) for row, most in
+                _filter_representatives(filters, admits, uses)]
+    for row, f, most in work:
+        def submit(i):
+            if f is None:
+                engine.submit(queries[i % len(queries)])
+            else:
+                engine.submit(queries[row], filter=f)
+
+        if engine.continuous and f is None:
+            # twice the pool: the second half refills slots of a live state
+            for i in range(2 * engine.slots):
+                submit(i)
+            engine.drain()
+        elif engine.continuous:
+            # one pool a filter; later sizes refill slots of a live state
+            for b in _pow2_upto(min(2 * engine.slots, most)):
+                for i in range(b):
+                    submit(i)
+                engine.drain()
+        else:
+            for b in _pow2_upto(min(engine.batch_size,
+                                    1 << (most - 1).bit_length())):
+                for i in range(b):
+                    submit(i)
+                engine.step(force=True)
     if engine.continuous:
-        # twice the pool: the second half refills slots of a live state
-        for i in range(2 * engine.slots):
-            engine.submit(queries[i % len(queries)])
-        engine.drain()
         for pool in engine._pools.values():
             for b in _pow2_upto(len(pool.requests)):
                 rows = np.zeros((b,), np.int64)
                 core = pool.session.finalize(
                     engine_mod._gather_rows(pool.state, rows))
                 np.asarray(core.ids)
-    else:
-        for b in _pow2_upto(engine.batch_size):
-            for i in range(b):
-                engine.submit(queries[i % len(queries)])
-            engine.step(force=True)
     engine.drain()
     engine.done.clear()
     if engine.obs.metrics.enabled:
@@ -149,6 +205,9 @@ class Counters:
             for sess in engine._sessions.values():
                 if sess is not None:
                     sess.complete = self._wrap_complete(sess.complete)
+            # a filter that first comes in the window opens a session then
+            planner = engine.searcher.planner
+            planner.round_session = self._wrap_session(planner.round_session)
         else:
             engine.searcher.execute = self._wrap_execute(
                 engine.searcher.execute)
@@ -166,6 +225,14 @@ class Counters:
             ex = execute(plan, queries)
             self._record(ex.counters, len(queries))
             return ex
+        return wrapped
+
+    def _wrap_session(self, round_session):
+        def wrapped(plan):
+            sess = round_session(plan)
+            if sess is not None:
+                sess.complete = self._wrap_complete(sess.complete)
+            return sess
         return wrapped
 
     def _wrap_complete(self, complete):

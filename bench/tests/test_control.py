@@ -7,7 +7,10 @@ against. On the CPU at a small size:
   correct on the sound program and not correct when the timed path is
   broken underneath: an answer altered where the search produces it,
   answers handed to the wrong requests, or a traversal cut short (its
-  answers keep exact distances; only the recall floor catches it).
+  answers keep exact distances; only the recall floor catches it);
+* with tags and per-request predicates, a whole run is correct under both
+  schedulers, and not correct when the engine drops the requests' filters;
+  the control, filtered too, still fails.
 """
 from __future__ import annotations
 
@@ -19,7 +22,7 @@ import numpy as np
 import pytest
 
 from bench import check, control, corpus, run
-from bench.tests.helpers import REPO, tiny_root
+from bench.tests.helpers import LABELS, REPO, tiny_root
 
 SEED = 2_900_000_011
 
@@ -49,10 +52,54 @@ def test_control_fails_and_the_reference_passes(name):
     assert control.readings(cfg, base, queries, truth, d32)["correct"]
 
 
-def _tiny_run(root, trace=0):
+@pytest.mark.parametrize("quantize", [None, "uint8"])
+def test_filtered_control_fails_and_the_reference_passes(quantize):
+    """With tags; and with vectors of whole numbers 0..255, which bfloat16
+    holds exactly, so that the control keeps 4 bits of each."""
+    with open(REPO / "bench" / "configs" / "sift128-l2.json") as f:
+        cfg = json.load(f)
+    cfg.update(num_base=2000, num_queries=256, labels=LABELS)
+    if quantize:
+        cfg["assumed"].update(quantize=quantize, center_mean=128.0,
+                              center_std=24.0, noise_std=24.0)
+    base, queries = corpus.make_corpus(cfg)
+    labels = corpus.make_labels(cfg)
+    sets = check.TagSets(labels.offsets, labels.tags)
+    preds = labels.predicates
+    if quantize:
+        ids, dists = control.control_answers(
+            base, queries, cfg["k"], "l2", tag_sets=sets, predicates=preds)
+        assert control.readings(cfg, base, queries, ids, dists,
+                                tag_sets=sets, predicates=preds)["correct"]
+    ids, dists = control.control_answers(
+        base, queries, cfg["k"], "l2", tag_sets=sets, predicates=preds,
+        precision=control.lower_precision(cfg))
+    assert sets.contains(ids, preds).all()
+    low = control.readings(cfg, base, queries, ids, dists, tag_sets=sets,
+                           predicates=preds)
+    assert not low["correct"]
+    gap = low["checks"]["dist_gap_max"]
+    assert gap["value"] > 3 * gap["limit"]
+    assert low["checks"]["bad_ids"]["value"] == 0
+    truth = check.exact_knn(queries, base, cfg["k"], "l2", tag_sets=sets,
+                            predicates=preds)
+    d32 = ((base[truth] - queries[:, None, :]) ** 2).sum(-1)
+    sound = control.readings(cfg, base, queries, truth, d32, tag_sets=sets,
+                             predicates=preds)
+    assert sound["correct"]
+    assert sound["checks"]["recall_at_10"]["value"] == 1.0
+    # the unfiltered answers break the predicates
+    plain = check.exact_knn(queries, base, cfg["k"], "l2")
+    d32 = ((base[plain] - queries[:, None, :]) ** 2).sum(-1)
+    dropped = control.readings(cfg, base, queries, plain, d32, tag_sets=sets,
+                               predicates=preds)
+    assert not dropped["correct"] and dropped["checks"]["bad_ids"]["value"]
+
+
+def _tiny_run(root, trace=0, err=None):
     args = run.parse(["--workload", "tiny", "--seed", str(SEED),
                       "--seconds", "1.5", "--trace", str(trace)])
-    return run.run(args, root=root, on_chip=False, err=io.StringIO())
+    return run.run(args, root=root, on_chip=False, err=err or io.StringIO())
 
 
 @pytest.fixture(scope="module")
@@ -127,3 +174,42 @@ def test_traversal_cut_short_is_not_correct(root, monkeypatch):
     assert not res["correct"] and res["failed"] > 0
     assert checks["dist_gap_max"]["value"] <= checks["dist_gap_max"]["limit"]
     assert checks["recall_at_10"]["value"] < checks["recall_at_10"]["limit"]
+
+
+@pytest.fixture(scope="module")
+def filtered_root(tmp_path_factory):
+    return tiny_root(tmp_path_factory.mktemp("filtered"), rate=30.0,
+                     labels=True)
+
+
+@pytest.mark.parametrize("scheduler", ["batch", "continuous"])
+def test_sound_filtered_run_is_correct(scheduler, filtered_root, tmp_path):
+    """Every request carries its pool row's predicate; the warm-up leaves
+    no filtered shape to compile in the window."""
+    root = filtered_root if scheduler == "batch" else tiny_root(
+        tmp_path, scheduler=scheduler, rate=30.0, labels=True)
+    err = io.StringIO()
+    res = _tiny_run(root, err=err)
+    assert res["correct"] and res["failed"] == 0
+    assert res["attempted"] == 45
+    assert " 0 compiles in the window" in err.getvalue()
+    assert "# filters: 45 filtered queries" in err.getvalue()
+    assert res["checks"]["recall_at_10"]["value"] >= 0.85
+
+
+def test_dropped_filters_are_not_correct(filtered_root, monkeypatch):
+    """The engine serves the requests without their filters: the answers
+    are the unfiltered neighbours, with exact distances, and break their
+    predicates."""
+    from repro.serve.engine import ServingEngine
+
+    orig = ServingEngine.submit
+
+    def dropped(self, query, filter=None, tenant=None):
+        return orig(self, query, tenant=tenant)
+    monkeypatch.setattr(ServingEngine, "submit", dropped)
+    res = _tiny_run(filtered_root)
+    checks = res["checks"]
+    assert not res["correct"] and res["failed"] > 0
+    assert checks["bad_ids"]["value"] > 0
+    assert checks["dist_gap_max"]["value"] <= checks["dist_gap_max"]["limit"]

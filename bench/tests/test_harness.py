@@ -102,8 +102,12 @@ def test_discovery_finds_added_files_by_name(tmp_path):
         json.dumps({"scheduler": "batch", "batch_size": 4,
                     "arrivals": "poisson", "load": 0.5, "knee_qps": 10}))
     cfg = json.loads((root / "bench/configs/sift128-l2.json").read_text())
-    cfg["name"] = "extra-cfg"
+    cfg.update(name="extra-cfg", adapter="bench/adapters/extra.py")
     (root / "bench/configs/extra-cfg.json").write_text(json.dumps(cfg))
+    (root / "bench/adapters").mkdir()
+    (root / "bench/adapters/extra.py").write_text(
+        "def attach(index, offsets, tags, to_corpus):\n    pass\n\n"
+        "def request_filter(tags):\n    return ('all', tags)\n")
     (root / "bench/metrics/extra_metric.py").write_text(
         "def read(record):\n    return record['x'] * 2\n")
     bench = json.loads((root / "BENCHMARK.json").read_text())
@@ -128,12 +132,25 @@ def test_discovery_finds_added_files_by_name(tmp_path):
     assert catalog.units(b)["extra_metric"] == "x"
     with pytest.raises(KeyError):
         catalog.workload(b, "missing")
+    extra = catalog.config(b, "extra-cfg", root)
+    assert catalog.adapter(extra, root).request_filter((3,)) == ("all", (3,))
+    assert catalog.adapter(catalog.config(b, "sift128-l2", root),
+                           root) is None
+    with pytest.raises(ValueError):
+        catalog.adapter(dict(extra, adapter="src/repro/__init__.py"), root)
 
 
 def test_every_benchmark_metric_has_a_reader_and_every_cell_its_files():
     b = catalog.load_benchmark(REPO)
     for m in b["per_layer"]:
         assert callable(catalog.reader(m["name"], REPO))
+    for c in b["configs"]:
+        config = catalog.config(b, c["name"], REPO)
+        adapter = catalog.adapter(config, REPO)
+        assert (adapter is None) == ("labels" not in config)
+        if adapter is not None:
+            assert callable(adapter.attach)
+            assert callable(adapter.request_filter)
     for w in b["workloads"]:
         assert catalog.config(b, w["config"], REPO)["name"] == w["config"]
         t = catalog.traffic(w["traffic"], REPO)
@@ -193,6 +210,78 @@ def test_recall_floor_fails_a_run_and_its_worst_requests():
     ok, failed, _ = check.verdict(r, {"dist_gap_max": 1e-4,
                                       "recall_at_10_min": 0.9})
     assert not ok and failed == 1
+
+
+def _tag_sets():
+    # rows: 0 {1, 2}, 1 {2}, 2 {}, 3 {1, 2, 5}, 4 {5}, 5 {1}
+    offsets = np.array([0, 2, 3, 3, 6, 7, 8])
+    tags = np.array([1, 2, 2, 1, 2, 5, 5, 1])
+    return check.TagSets(offsets, tags)
+
+
+def test_filtered_reference_matches_a_naive_loop():
+    rng = np.random.default_rng(7)
+    n, vocab = 400, 12
+    counts = rng.integers(0, 5, size=n)
+    offsets = np.concatenate([[0], np.cumsum(counts)])
+    rows = [np.sort(rng.choice(vocab, size=c, replace=False))
+            for c in counts]
+    sets = check.TagSets(offsets, np.concatenate(rows))
+    base = rng.standard_normal((n, 8)).astype(np.float32)
+    queries = rng.standard_normal((40, 8)).astype(np.float32)
+    predicates = np.full((40, 2), -1)
+    predicates[:, 0] = rng.integers(0, vocab, size=40)
+    predicates[20:, 1] = (predicates[20:, 0] + 1 + rng.integers(
+        0, vocab - 1, size=20)) % vocab
+    predicates[5] = -1                              # no predicate: all rows
+    for metric in ("l2", "angular"):
+        got = check.exact_knn(queries, base, 5, metric, chunk=16,
+                              tag_sets=sets, predicates=predicates)
+        for q, pred, ids in zip(queries, predicates, got):
+            want = set(pred[pred >= 0].tolist())
+            ok = [i for i in range(n) if want <= set(rows[i].tolist())]
+            x = base[ok].astype(np.float64)
+            if metric == "angular":
+                x = x / np.linalg.norm(x, axis=1, keepdims=True)
+                d = -(x @ q.astype(np.float64))
+            else:
+                d = ((x - q.astype(np.float64)) ** 2).sum(1)
+            near = [ok[i] for i in np.argsort(d, kind="stable")[:5]]
+            assert ids[:len(near)].tolist() == near
+            assert np.all(ids[len(near):] == -1)     # fewer than k pass
+    ts = _tag_sets()
+    assert ts.count([1, 2]) == 2 and ts.count([5, -1]) == 2
+    assert ts.count([-1, -1]) == 6 and ts.count([7]) == 0
+    assert ts.admitted(np.array([[1, 2], [-1, -1]])).tolist() == [
+        [True, False, False, True, False, False], [True] * 6]
+
+
+def test_an_id_outside_its_predicate_is_a_bad_id():
+    ts = _tag_sets()
+    base = np.eye(6, dtype=np.float32)
+    queries = base[[0, 4]]
+    predicates = np.array([[1, 2], [5, -1]])
+    ids = np.array([[0, 3], [4, 3]])                # all pass
+    dists = ((base[ids] - queries[:, None, :]) ** 2).sum(-1)
+    ok = check.compare(queries, base, ids, dists, np.ones(2, bool), "l2",
+                       tag_sets=ts, predicates=predicates)
+    assert ok["bad_ids"] == 0 and ok["recall_at_10"] == 1.0
+    assert ts.contains(ids, predicates).all()
+    assert ts.contains(np.array([[0, -1]]), predicates[:1]).tolist() == [
+        [True, False]]
+    ids = np.array([[0, 5], [4, 3]])                # row 5 lacks tag 2
+    dists = ((base[ids] - queries[:, None, :]) ** 2).sum(-1)
+    r = check.compare(queries, base, ids, dists, np.ones(2, bool), "l2",
+                      tag_sets=ts, predicates=predicates)
+    assert r["bad_ids"] == 1 and r["_row_bad"].tolist() == [True, False]
+    assert r["dist_gap_max"] < 1e-6
+    correct, failed, shown = check.verdict(r, {"dist_gap_max": 1e-4,
+                                               "recall_at_10_min": 0.0})
+    assert not correct and failed == 1
+    assert list(shown) == list(check.CHECKS)
+    # the same answers, unfiltered, are sound
+    r = check.compare(queries, base, ids, dists, np.ones(2, bool), "l2")
+    assert r["bad_ids"] == 0
 
 
 def test_gc_pauses_are_timed_inside_the_window():
