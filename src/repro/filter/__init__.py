@@ -1,15 +1,18 @@
-"""Filtered ANN subsystem: attribute store, FilterSpec predicates,
-selectivity-adaptive filtered traversal, and the per-tile bitmap plumbing
-for near-storage predicate pushdown (billed by ``nand.simulator``)."""
+"""Filtered ANN subsystem: attribute store (scalar columns and tag-set
+fields with posting lists), FilterSpec predicates (Eq / Range / In over
+columns, Contains over tag sets), selectivity-adaptive filtered traversal,
+and the per-tile bitmap plumbing for near-storage predicate pushdown
+(billed by ``nand.simulator``)."""
 from repro.filter.attributes import (
     AttributeStore,
+    TagField,
     bitmap_popcount,
     encode_categorical,
     pack_bitmap,
     random_attributes,
     unpack_bitmap,
 )
-from repro.filter.spec import ALL, Eq, FilterSpec, In, Range
+from repro.filter.spec import ALL, Contains, Eq, FilterSpec, In, Range
 from repro.filter.traversal import (
     FilteredSearchResult,
     adapt_search_cfg,
@@ -39,11 +42,13 @@ def attach_attributes(index, store: AttributeStore) -> AttributeStore:
 __all__ = [
     "ALL",
     "AttributeStore",
+    "Contains",
     "Eq",
     "FilterSpec",
     "FilteredSearchResult",
     "In",
     "Range",
+    "TagField",
     "adapt_search_cfg",
     "attach_attributes",
     "bitmap_popcount",

@@ -1,15 +1,19 @@
 """FilterSpec — AND-composed attribute predicates over the corpus.
 
-A spec is a tuple of predicates (equality / inclusive range / IN-set) over
-named integer attribute columns; categorical fields are integer-coded by the
-caller (``attributes.encode_categorical``). Specs are frozen and hashable so
-the serving engine can batch requests by filter hash, and ``evaluate`` is
-operator-only arithmetic that works identically on numpy (host-side mask
-compilation) and jnp (device-side evaluation) column matrices.
+A spec is a tuple of predicates: equality / inclusive range / IN-set over
+named integer attribute columns (categorical fields are integer-coded by the
+caller, ``attributes.encode_categorical``), and containment over a tag-set
+field (the row's set of tags holds every tag of the predicate). Specs are
+frozen and hashable so the serving engine can batch requests by filter
+hash. ``evaluate`` handles the column predicates with operator-only
+arithmetic that works identically on numpy (host-side mask compilation) and
+jnp (device-side evaluation) column matrices; containment is answered by
+the store's inverted index (``AttributeStore.mask``).
 
 Compose with ``&``::
 
     spec = FilterSpec.eq("category", 3) & FilterSpec.range("price", 0, 49)
+    spec = FilterSpec.contains("tags", (17, 4096)) & FilterSpec.eq("year", 9)
 """
 from __future__ import annotations
 
@@ -45,7 +49,20 @@ class In:
                            tuple(int(v) for v in self.values))
 
 
-Predicate = Union[Eq, Range, In]
+@dataclass(frozen=True)
+class Contains:
+    """The row's tag set in ``field`` holds every tag of ``tags``. Tags are
+    normalised to a sorted tuple of distinct ints, so ``{3, 7}`` and
+    ``(7, 3, 3)`` are one predicate (one plan-cache key)."""
+    field: str
+    tags: Tuple[int, ...]
+
+    def __post_init__(self):
+        object.__setattr__(self, "tags",
+                           tuple(sorted({int(t) for t in self.tags})))
+
+
+Predicate = Union[Eq, Range, In, Contains]
 
 
 def _eval_predicate(p: Predicate, col, xp):
@@ -88,6 +105,11 @@ class FilterSpec:
     def isin(field: str, values) -> "FilterSpec":
         return FilterSpec((In(field, tuple(values)),))
 
+    @staticmethod
+    def contains(field: str, tags) -> "FilterSpec":
+        """Rows whose tag set in ``field`` holds every tag of ``tags``."""
+        return FilterSpec((Contains(field, tuple(tags)),))
+
     def __and__(self, other: "FilterSpec") -> "FilterSpec":
         return FilterSpec(self.predicates + other.predicates)
 
@@ -103,6 +125,11 @@ class FilterSpec:
         """(N, F) column matrix -> (N,) boolean pass mask."""
         mask = xp.ones(values.shape[0], bool)
         for p in self.predicates:
+            if isinstance(p, Contains):
+                raise TypeError(
+                    f"containment on {p.field!r} is answered by the "
+                    "attribute store's tag index (AttributeStore.mask), "
+                    "not by a column")
             try:
                 col = values[:, fields.index(p.field)]
             except ValueError:

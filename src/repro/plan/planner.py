@@ -33,6 +33,7 @@ did (see tests/test_plan.py for the enforced equivalence matrix).
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import numpy as np
@@ -179,6 +180,13 @@ class QueryPlanner:
         self._mask_tokens = 0
         self.plan_cache_hits = 0
         self.plan_cache_misses = 0
+        # filter compilation (always counted; the registry's counters of
+        # the same names only with metrics on): pass masks built, posting
+        # list entries their containment predicates read, and host seconds
+        # in ``filter-compile`` spans
+        self.filter_masks_compiled = 0
+        self.filter_posting_entries = 0
+        self.filter_compile_s = 0.0
         self.obs = obs or NULL_OBS
         # every blocking device->host read of this target's served path
         self.syncs = DeviceSyncs(self.obs)
@@ -203,7 +211,10 @@ class QueryPlanner:
                                          tenant=request.tenant)
             return cached
         self.plan_cache_misses += 1
-        plan = self._compile(spec, request)
+        if spec is None:
+            plan = self._compile(spec, request)
+        else:
+            plan = self._compile_filtered(spec, request)
         self._plan_cache[key] = plan
         if self.obs.enabled:
             self.obs.metrics.counter("plan_cache_misses",
@@ -212,6 +223,25 @@ class QueryPlanner:
                                      strategy=plan.strategy,
                                      tenant=request.tenant)
         return plan
+
+    def _compile_filtered(self, spec: FilterSpec,
+                          request: SearchRequest) -> QueryPlan:
+        """``_compile`` of a filtered request (its mask and regime) inside a
+        ``filter-compile`` span, its host time counted."""
+        t0 = time.perf_counter()
+        with self.obs.tracer.span("filter-compile", cat="plan") as sp:
+            plan = self._compile(spec, request)
+            sp.set(strategy=plan.strategy, selectivity=plan.selectivity)
+        dt = time.perf_counter() - t0
+        self.filter_compile_s += dt
+        if self.obs.metrics.enabled:
+            self.obs.metrics.counter("filter_compile_s", dt)
+        return plan
+
+    def filter_compile_stats(self) -> dict:
+        return {"filter_masks_compiled": self.filter_masks_compiled,
+                "filter_posting_entries": self.filter_posting_entries,
+                "filter_compile_s": self.filter_compile_s}
 
     def _effective_cfg(self, request: SearchRequest) -> SearchConfig:
         cfg = self.cfg
@@ -236,8 +266,15 @@ class QueryPlanner:
                     "attributes= to Searcher.open / ServingEngine or attach "
                     "one to the index"
                 )
-            mask = np.asarray(self.attributes.mask(spec), bool)
+            mask, read = self.attributes.mask_and_reads(spec)
+            mask = np.asarray(mask, bool)
             self._mask_cache[spec] = mask
+            self.filter_masks_compiled += 1
+            self.filter_posting_entries += int(read)
+            if self.obs.metrics.enabled:
+                self.obs.metrics.counter("filter_masks_compiled")
+                self.obs.metrics.counter("filter_posting_entries",
+                                         float(read))
         return mask
 
     def _filter_strategy(self, mask: np.ndarray, k: int) -> Tuple[str, float]:

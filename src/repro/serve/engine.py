@@ -349,12 +349,15 @@ class ServingEngine:
     @property
     def stats(self) -> dict:
         """Back-compat dict view, derived from the structured
-        ``EngineStats`` with the planner's plan-cache counters and its count
-        of blocking device->host reads (``device_syncs``) merged in at read
-        time (the planner owns both; nothing is hand-synced)."""
+        ``EngineStats`` with the planner's plan-cache counters, its count
+        of blocking device->host reads (``device_syncs``) and its filter
+        compilation counters (``filter_masks_compiled``,
+        ``filter_posting_entries``, ``filter_compile_s``) merged in at read
+        time (the planner owns them; nothing is hand-synced)."""
         d = self._stats.as_dict()
         d.update(self.searcher.plan_cache_stats())
         d["device_syncs"] = self.searcher.planner.syncs.total
+        d.update(self.searcher.planner.filter_compile_stats())
         return d
 
     def slo_status(self) -> dict:

@@ -187,7 +187,8 @@ class MutableIndex:
     def insert(self, vec: np.ndarray, attrs=None) -> int:
         """Insert a vector (and, when the index carries an attribute store,
         its attribute row — required so filters stay total over the live
-        corpus)."""
+        corpus). A store with tag-set fields takes no inserts: the row is
+        refused before anything mutates."""
         attr_row = None
         if self.attributes is not None:
             if attrs is None:

@@ -443,3 +443,252 @@ def test_sharded_corpus_upgrades_pre_shard_config(tiny_index):
     assert part.num_tiles == 2
     ref, _ = tiny_index.sharded_corpus(num_tiles=2)
     assert np.asarray(tiled.adjacency).shape == np.asarray(ref.adjacency).shape
+
+
+# ---------------------------------------------------------------------------
+# Tag-set fields: CSR + posting lists, containment predicates, served
+# through ServingEngine against the plain filtered reference
+# ---------------------------------------------------------------------------
+
+TAG_VOCAB = 200_386          # the NeurIPS'23 filtered track's yfcc-10M vocabulary
+TAG_ROWS, TAG_DIM, TAG_QUERIES = 1500, 192, 12
+
+
+@pytest.fixture(scope="module")
+def tag_data():
+    """Seeded uint8-valued 192-d vectors (a Gaussian mixture rounded and
+    clipped to 0..255; clusters of about 16, under the build list, so the
+    graph's kNN lists reach across clusters) and 6-14 tags a row drawn by Zipf popularity over
+    the full vocabulary, as CSR plus the Python sets the reference uses."""
+    rng = np.random.default_rng(20261019)
+    centers = rng.normal(128.0, 24.0, (96, TAG_DIM))
+
+    def members(n):
+        x = centers[rng.integers(0, len(centers), n)] \
+            + rng.normal(0.0, 24.0, (n, TAG_DIM))
+        return np.clip(np.rint(x), 0, 255).astype(np.float32)
+
+    base, queries = members(TAG_ROWS), members(TAG_QUERIES)
+    pop = 1.0 / np.arange(1, TAG_VOCAB + 1)
+    drawn = rng.choice(TAG_VOCAB, size=(TAG_ROWS, 14), p=pop / pop.sum())
+    counts = rng.integers(6, 15, TAG_ROWS)
+    rows = [np.unique(drawn[i, :counts[i]]) for i in range(TAG_ROWS)]
+    offsets = np.concatenate([[0], np.cumsum([len(r) for r in rows])])
+    tags = np.concatenate(rows).astype(np.int32)
+    sets = [set(r.tolist()) for r in rows]
+    freq = np.bincount(tags, minlength=TAG_VOCAB)
+    return dict(base=base, queries=queries, offsets=offsets, tags=tags,
+                sets=sets, freq=freq)
+
+
+def _admitted(sets, tags) -> np.ndarray:
+    """The reference's pass mask: Python ``set`` containment, row by row."""
+    want = set(tags)
+    return np.asarray([want <= s for s in sets], bool)
+
+
+def _predicates(freq) -> dict:
+    """Predicates by regime over the 1,500 rows (scan: at most 2% pass,
+    ``FilterConfig.brute_force_selectivity``; masked: more), each admitting
+    at least k = 10 rows, and ones that admit none."""
+    order = np.argsort(-freq, kind="stable")
+    rare = [int(t) for t in order if 12 <= freq[t] <= 25]
+    common = [int(t) for t in order[:4]]
+    absent = int(np.flatnonzero(freq == 0)[-1])
+    return {"masked": [(common[0],), (common[1],), (common[0], common[1])],
+            "scan": [(rare[0],), (rare[1],), (common[0], rare[2])],
+            "none": [(absent,), (common[0], absent), (TAG_VOCAB + 5,),
+                     (-1,), (1 << 40,)]}
+
+
+@pytest.fixture(scope="module")
+def tag_index(tag_data):
+    from repro.configs.base import (
+        DatasetConfig, GraphConfig, PQConfig,
+    )
+    from repro.core import build_index
+    from repro.core.dataset import Dataset
+
+    cfg = ProximaConfig(
+        dataset=DatasetConfig(name="yfcc-like", num_base=TAG_ROWS,
+                              num_queries=0, dim=TAG_DIM, metric="l2"),
+        pq=PQConfig(num_subvectors=48, num_centroids=128, kmeans_iters=8),
+        graph=GraphConfig(max_degree=24, build_list_size=48, alpha=1.2),
+        search=SearchConfig(k=10, list_size=64, t_init=16, t_step=8,
+                            repetition_rate=3, beta=1.06),
+        hot_node_fraction=0.03,
+    )
+    base = tag_data["base"]
+    ds = Dataset(base=base, queries=np.zeros((0, TAG_DIM), np.float32),
+                 gt=np.zeros((0, 10), np.int32), metric="l2",
+                 config=cfg.dataset)
+    index = build_index(cfg, dataset=ds, reorder_samples=24)
+    to_corpus = np.arange(TAG_ROWS) if index.reordering is None \
+        else np.asarray(index.reordering.inv, np.int64)
+    store = AttributeStore.from_columns(
+        {}, {"tags": (tag_data["offsets"], tag_data["tags"])})
+    attach_attributes(index, store.permuted(to_corpus))
+    return index, to_corpus
+
+
+@pytest.mark.parametrize("permuted", [False, True])
+@pytest.mark.parametrize("regime", ["masked", "scan", "none"])
+def test_tag_mask_matches_python_sets(tag_data, regime, permuted):
+    store = AttributeStore.from_columns(
+        {}, {"tags": (tag_data["offsets"], tag_data["tags"])})
+    sets = tag_data["sets"]
+    if permuted:
+        perm = np.random.default_rng(3).permutation(TAG_ROWS)
+        store, sets = store.permuted(perm), [sets[i] for i in perm]
+    tf = store.tag_fields["tags"]
+    for tags in _predicates(tag_data["freq"])[regime]:
+        spec = FilterSpec.contains("tags", tags)
+        want = _admitted(sets, tags)
+        mask, read = store.mask_and_reads(spec)
+        np.testing.assert_array_equal(mask, want)
+        assert int(mask.sum()) == int(want.sum())
+        assert store.selectivity(spec) == pytest.approx(want.mean())
+        # posting lists are read shortest first, and not past an empty one
+        lens = sorted(len(tf.postings(t)) for t in tags)
+        assert read == (lens[0] if lens[0] == 0 else sum(lens))
+        for t in tags:
+            np.testing.assert_array_equal(
+                tf.postings(t), np.flatnonzero(_admitted(sets, (t,))))
+
+
+def test_contains_spec_normalised_and_mixed_with_columns():
+    a = FilterSpec.contains("tags", {7, 3})
+    assert a == FilterSpec.contains("tags", (3, 7, 3, 7))
+    assert hash(a) == hash(FilterSpec.contains("tags", [np.int64(3), 7]))
+    assert a.predicates[0].tags == (3, 7)
+    store = AttributeStore.from_columns(
+        {"cat": np.asarray([0, 1, 1, 0])},
+        {"tags": (np.asarray([0, 2, 3, 5, 5]), np.asarray([3, 7, 3, 3, 7]))})
+    np.testing.assert_array_equal(store.mask(a), [1, 0, 1, 0])
+    both = a & FilterSpec.eq("cat", 1)
+    np.testing.assert_array_equal(store.mask(both), [0, 0, 1, 0])
+    np.testing.assert_array_equal(
+        store.mask(FilterSpec.eq("cat", 0) & FilterSpec.contains("tags", ())),
+        [1, 0, 0, 1])                      # no tag to hold: every row holds it
+    np.testing.assert_array_equal(
+        store.mask(both), store.permuted([0, 1, 2, 3]).mask(both))
+    assert store.attr_bits == 32 + 32 * 5 // 4     # column + 32 a tag a row
+    with pytest.raises(KeyError, match="unknown tag field"):
+        store.mask(FilterSpec.contains("labels", (3,)))
+    with pytest.raises(TypeError, match="tag index"):
+        a.evaluate(store.values, store.fields)
+    with pytest.raises(ValueError, match="one tag twice"):
+        AttributeStore.from_columns({}, {"tags": ([0, 2], [4, 4])})
+
+
+def test_tag_store_holds_nothing_of_rows_by_vocabulary(tag_data):
+    store = AttributeStore.from_columns(
+        {}, {"tags": (tag_data["offsets"], tag_data["tags"])})
+    stored = len(tag_data["tags"])
+    assert int(tag_data["tags"].max()) > TAG_VOCAB // 2    # the tail is used
+    tf = store.tag_fields["tags"]
+    arrays = [store.values, tf.offsets, tf.tags, tf._rows, tf._keys,
+              tf._starts]
+    assert all(a.size <= TAG_ROWS + stored + 1 for a in arrays)
+    assert store.nbytes == sum(a.nbytes for a in arrays)
+    assert store.nbytes <= 8 * (2 * TAG_ROWS + 4 * stored)
+    assert store.nbytes * 1000 < TAG_ROWS * TAG_VOCAB
+
+
+@pytest.mark.parametrize("regime", ["masked", "scan"])
+def test_served_tag_filters_match_reference(tag_data, tag_index, regime):
+    """Both regimes through ``ServingEngine`` against the plain reference:
+    float64 exact top-k among the rows Python ``set`` containment admits."""
+    from repro.serve.engine import ServingEngine
+
+    index, to_corpus = tag_index
+    base64 = tag_data["base"].astype(np.float64)
+    eng = ServingEngine(index, batch_size=8, flush_us=0.0)
+    preds = _predicates(tag_data["freq"])[regime]
+    reqs = [(qi, tags, eng.submit(q, filter=FilterSpec.contains("tags", tags)))
+            for tags in preds for qi, q in enumerate(tag_data["queries"])]
+    eng.drain()
+    assert {eng.done[r].plan.strategy for _, _, r in reqs} == {regime}
+    assert (eng.stats["filter_scan_batches"] > 0) == (regime == "scan")
+    assert eng.stats["filtered_queries"] == len(reqs)
+    recalls, gaps = [], []
+    for qi, tags, rid in reqs:
+        r = eng.done[rid]
+        ids = to_corpus[np.asarray(r.ids)]           # corpus row ids
+        admitted = np.flatnonzero(_admitted(tag_data["sets"], tags))
+        assert len(admitted) >= 10
+        assert set(ids.tolist()) <= set(admitted.tolist())
+        q = tag_data["queries"][qi].astype(np.float64)
+        truth = admitted[exact_knn(q[None], base64[admitted], 10, "l2")[0]]
+        recalls.append(len(set(ids.tolist()) & set(truth.tolist())) / 10)
+        exact = ((base64[ids] - q) ** 2).sum(-1)
+        gaps.append(np.abs(np.asarray(r.dists, np.float64) - exact).max())
+    # the track's own operating point; this data reads 0.997 (masked), 1.0
+    # (scan)
+    assert np.mean(recalls) >= 0.9
+    # whole-number vectors: every squared difference, and every partial sum
+    # (at most 192 * 255^2 < 2^24), is exact in float32
+    assert max(gaps) == 0.0
+
+
+def test_empty_tag_predicates_answer_nothing(tag_data, tag_index):
+    from repro.serve.engine import ServingEngine
+
+    index, _ = tag_index
+    eng = ServingEngine(index, batch_size=8, flush_us=0.0)
+    q = tag_data["queries"][0]
+    rids = [eng.submit(q, filter=FilterSpec.contains("tags", tags))
+            for tags in _predicates(tag_data["freq"])["none"]]
+    eng.drain()
+    for rid in rids:
+        assert eng.done[rid].plan.strategy == "empty"
+        assert (np.asarray(eng.done[rid].ids) == -1).all()
+
+
+def test_filter_compile_span_and_counters(tag_data, tag_index):
+    from repro.configs.base import ObsConfig
+    from repro.serve.engine import ServingEngine
+
+    index, _ = tag_index
+    store = index.attributes
+    eng = ServingEngine(index, batch_size=8, flush_us=0.0,
+                        obs=ObsConfig(metrics=True, tracing=True))
+    preds = _predicates(tag_data["freq"])
+    specs = [FilterSpec.contains("tags", t)
+             for t in (preds["masked"][2], preds["scan"][0])]
+    for spec in specs + specs[:1]:           # the third is a plan-cache hit
+        eng.submit(tag_data["queries"][0], filter=spec)
+    eng.drain()
+    st = eng.stats
+    assert st["filter_masks_compiled"] == 2
+    assert st["filter_posting_entries"] == sum(
+        store.mask_and_reads(s)[1] for s in specs)
+    assert st["filter_compile_s"] > 0
+    m = eng.obs.metrics
+    assert m.counter_total("filter_masks_compiled") == 2
+    assert m.counter_total("filter_posting_entries") == \
+        st["filter_posting_entries"]
+    assert m.counter_total("filter_compile_s") == pytest.approx(
+        st["filter_compile_s"])
+    spans = [e for e in eng.obs.tracer.events()
+             if e["ph"] == "X" and e["name"] == "filter-compile"]
+    assert len(spans) == 2 and {e["cat"] for e in spans} == {"plan"}
+    assert {e["args"]["strategy"] for e in spans} == {"masked", "scan"}
+
+
+def test_mutable_index_refuses_tag_field_insert(tag_data, tag_index):
+    from repro.stream import MutableIndex
+
+    index, to_corpus = tag_index
+    store = index.attributes
+    mut = MutableIndex(index, attributes=store)
+    before = (len(mut.delta), mut.next_ext, len(store), store.nbytes)
+    with pytest.raises(NotImplementedError, match="tag-set fields"):
+        mut.insert(tag_data["queries"][0], attrs={"tags": (1, 2)})
+    assert (len(mut.delta), mut.next_ext, len(store), store.nbytes) == before
+    # the tag filter still serves reads through the merged path
+    tags = _predicates(tag_data["freq"])["masked"][2]
+    res = mut.search(tag_data["queries"][:2],
+                     filter_spec=FilterSpec.contains("tags", tags))
+    ids = to_corpus[np.asarray(res.ids)[np.asarray(res.ids) >= 0]]
+    assert ids.size and _admitted(tag_data["sets"], tags)[ids].all()

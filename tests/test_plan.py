@@ -306,15 +306,17 @@ def test_engine_stats_derived_from_dataclass(tiny_index):
     d = eng.stats
     assert d["batches"] == 1 and d["queries"] == 4
     assert d["pool_dispatches"] == 0 == d["pool_rounds"]   # batch mode
-    # plan-cache counters and the device-sync count surface through the
-    # dict view (merged from the planner at read time — they are not
-    # EngineStats fields)
+    # plan-cache counters, the device-sync count and the filter
+    # compilation counters surface through the dict view (merged from the
+    # planner at read time — they are not EngineStats fields)
     assert d["plan_cache_misses"] >= 1
     assert d["plan_cache_hits"] >= 3
     assert d["device_syncs"] >= 1
+    assert d["filter_masks_compiled"] == 0 == d["filter_compile_s"]
     assert set(d) == set(EngineStats().as_dict()) | {
         "plan_cache_hits", "plan_cache_misses",
-        "device_syncs"}, "dict view drifted"
+        "device_syncs", "filter_masks_compiled", "filter_posting_entries",
+        "filter_compile_s"}, "dict view drifted"
 
 
 def test_validate_attribute_store_shared_helper(tiny_index, tiny_store):
