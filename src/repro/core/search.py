@@ -493,7 +493,8 @@ def _finalize_batch(corpus: Corpus, cfg: SearchConfig, metric: str,
 # vmapped while_loop lowers to exactly this select-guarded step).  The
 # scheduler dispatches ``graph_search_advance``: the same step, looped on the
 # device until the first lane quiesces, so the host syncs once per retire
-# point instead of once per round.
+# point instead of once per round, and retires the quiesced lanes through
+# ``finalize_rows``: gather, finalize and pack in one program, one copy.
 
 
 class SearchState(NamedTuple):
@@ -644,6 +645,50 @@ def finalize_search(
                            state.queries, state.lanes)
 
 
+# the lane fields ``_finalize_batch`` reads; the Bloom bits, ADTs,
+# ``evaluated`` and ``prev_topk`` never leave the pool
+_FINALIZE_LANE_FIELDS = ("ids", "dists", "acc", "t", "n_hops", "n_pq",
+                         "n_acc", "n_hot", "n_free", "rounds")
+
+
+@partial(jax.jit, static_argnames=("cfg", "metric"))
+def finalize_rows(
+    corpus: Corpus,
+    state: SearchState,
+    rows: jnp.ndarray,
+    cfg: SearchConfig,
+    metric: str = "l2",
+    node_mask: jnp.ndarray | None = None,
+) -> jnp.ndarray:
+    """The continuous scheduler's retire in one program: gather the lanes
+    ``rows`` (B,) of ``state`` — only the fields the rerank reads — run
+    ``_finalize_batch`` on them, and pack the result into one int32
+    ``(B, 2k + 6)`` array, so the host reads it in one copy: ids, then the
+    distances' bits (``lax.bitcast_convert_type``, exact), then
+    ``n_hops``, ``n_pq``, ``n_acc``, ``n_hot_hops``, ``n_free_pq`` and
+    ``rounds``.  ``unpack_rows`` undoes it on the host."""
+    lanes = state.lanes._replace(**{f: getattr(state.lanes, f)[rows]
+                                    for f in _FINALIZE_LANE_FIELDS})
+    r = _finalize_batch(corpus, cfg, metric, node_mask, state.queries[rows],
+                        lanes)
+    counters = jnp.stack([r.n_hops, r.n_pq, r.n_acc, r.n_hot_hops,
+                          r.n_free_pq, r.rounds], axis=1)
+    return jnp.concatenate(
+        [r.ids, jax.lax.bitcast_convert_type(r.dists, jnp.int32), counters],
+        axis=1)
+
+
+def unpack_rows(packed: np.ndarray, k: int) -> SearchResult:
+    """A host copy of ``finalize_rows``' packed array -> ``SearchResult`` of
+    NumPy views into it (distances reinterpreted as float32, bit for bit)."""
+    c = packed[:, 2 * k:]
+    return SearchResult(ids=packed[:, :k],
+                        dists=packed[:, k:2 * k].view(np.float32),
+                        n_hops=c[:, 0], n_pq=c[:, 1], n_acc=c[:, 2],
+                        n_hot_hops=c[:, 3], n_free_pq=c[:, 4],
+                        rounds=c[:, 5])
+
+
 def graph_search_stepped(
     corpus: Corpus,
     queries: jnp.ndarray,
@@ -716,6 +761,7 @@ def jit_cache_sizes() -> dict:
         ("graph_search_step", graph_search_step),
         ("graph_search_advance", graph_search_advance),
         ("finalize_search", finalize_search),
+        ("finalize_rows", finalize_rows),
     ):
         if hasattr(fn, "_cache_size"):
             out[name] = int(fn._cache_size())

@@ -1,10 +1,11 @@
 """Blocking device→host reads of the served path, through one helper.
 
 Every read that makes the host wait for the device — the planner's result
-fetch, a round session's ``active`` and ``rounds`` pulls, the engine's
-retire fetch — goes through :meth:`DeviceSyncs.get`, which counts one
-sync under its call site.  ``total`` always counts (one integer add); the
-registry's ``device_syncs{site=...}`` only when metrics are on.
+fetch, a round session's ``active`` and ``rounds`` pulls, its retire
+fetch — goes through :meth:`DeviceSyncs.get`, which counts one sync under
+its call site.  ``total`` (syncs) and ``copies`` (arrays copied) always
+count (integer adds); the registry's ``device_syncs{site=...}`` only when
+metrics are on.
 
 With the tracer on, the wait for the device (span ``device-wait``) and the
 copy to the host (span ``fetch``) are timed apart.  That costs a second
@@ -24,11 +25,12 @@ class DeviceSyncs:
     one per opened target; its round sessions and the serving engine use
     it too)."""
 
-    __slots__ = ("obs", "total")
+    __slots__ = ("obs", "total", "copies")
 
     def __init__(self, obs):
         self.obs = obs
         self.total = 0
+        self.copies = 0
 
     def get(self, site: str, arrays):
         """``arrays`` (one device array, or a tuple or namedtuple of them)
@@ -42,6 +44,7 @@ class DeviceSyncs:
             obs.metrics.counter("device_syncs", site=site)
         single = not isinstance(arrays, tuple)
         leaves = (arrays,) if single else arrays
+        self.copies += len(leaves)
         tracer = obs.tracer
         if tracer.enabled:
             with tracer.span("device-wait", cat="plan"):

@@ -6,7 +6,8 @@ where ``QueryPlanner.execute`` runs the plan's whole traversal inside one
 ``lax.while_loop``, a session exposes the SAME traversal one round at a time
 (``init`` / ``step`` / ``active`` / ``finalize``), or up to the next round
 on which a lane quiesces (``advance``), so an iteration-level scheduler
-(``ServingEngine(continuous=True)``) can retire finished lanes and refill
+(``ServingEngine(continuous=True)``) can retire finished lanes (``retire``:
+finalize chosen lanes in one dispatch, read them in one copy) and refill
 their slots between rounds.  ``complete`` then applies the plan's
 post-processing (filtered-result wrapping, or the merged path's delta /
 tombstone fusion) to a retired lane batch, producing the same plan-layer
@@ -138,6 +139,20 @@ class RoundSession:
         with self.planner.obs.tracer.span("finalize", cat="plan"):
             return finalize_search(self.corpus, state, self.cfg, self.metric,
                                    self._mask)
+
+    def retire(self, state, rows: np.ndarray):
+        """Finalize the lanes ``rows`` (padded to a power of two) of a pool
+        state in one dispatch and read them in one copy, counted under
+        ``retire`` -> core ``SearchResult`` of host arrays, the same values
+        as ``finalize(_gather_rows(state, rows))`` with ``rounds`` read
+        from the lanes."""
+        from repro.core.search import finalize_rows, unpack_rows
+
+        with self.planner.obs.tracer.span("finalize", cat="plan"):
+            packed = finalize_rows(self.corpus, state, rows, self.cfg,
+                                   self.metric, self._mask)
+        return unpack_rows(self.planner.syncs.get("retire", packed),
+                           int(self.cfg.k))
 
     def record_round(self, log, qids, state, select=None) -> None:
         """Append one per-round telemetry record per (selected) lane to an

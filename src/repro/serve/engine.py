@@ -163,9 +163,8 @@ _select_jit = None
 def _select_lanes(mask: np.ndarray, new, old):
     """Per-lane select over two same-shape ``SearchState``s: lane i comes
     from ``new`` where ``mask[i]`` — the fixed-shape slot-refill primitive
-    (no concatenation, no shape change, no recompile).  Jitted as one call
-    for the same reason as ``_gather_rows``: per-leaf eager ``where``s cost
-    a dispatch per state field."""
+    (no concatenation, no shape change, no recompile).  Jitted as one call:
+    per-leaf eager ``where``s cost a dispatch per state field."""
     global _select_jit
     import jax
     import jax.numpy as jnp
@@ -185,12 +184,11 @@ _gather_jit = None
 
 
 def _gather_rows(state, rows: np.ndarray):
-    """Row-gather a ``SearchState`` down to the given lanes (device-side).
-    Retiring finalizes only the quiesced rows — padded to a power-of-two
-    bucket so the rerank kernel compiles at log2(slots)+1 shapes per plan
-    instead of reranking the whole pool on every retiring tick.  Jitted as
-    ONE call: an eager per-leaf gather costs a device dispatch per state
-    field, which dominated the tick."""
+    """Row-gather a ``SearchState`` down to the given lanes (device-side),
+    every leaf, in one jitted call.  The served retire does not use it
+    (``RoundSession.retire`` gathers, finalizes and packs in one program);
+    ``finalize(_gather_rows(state, rows))`` is the stepped reference that
+    retire is tested against."""
     global _gather_jit
     import jax
 
@@ -287,7 +285,10 @@ class ServingEngine:
             if sess0 is not None:
                 z = np.zeros((self.slots, dummy.shape[1]), np.float32)
                 st, _, _ = sess0.advance(sess0.init(z), 1)
-                sess0.finalize(st)
+                # and the retire at every power-of-two row count a retire
+                # can pad to
+                for b in range(int(math.log2(next_pow2(self.slots))) + 1):
+                    sess0.retire(st, np.zeros((1 << b,), np.int64))
         # recompile watchdog baselined AFTER warm-up, so only serving-time
         # jit-cache growth is judged against the pow2-bucket x plan budget
         self._watch = KernelWatch(self.obs.metrics) \
@@ -349,14 +350,17 @@ class ServingEngine:
     @property
     def stats(self) -> dict:
         """Back-compat dict view, derived from the structured
-        ``EngineStats`` with the planner's plan-cache counters, its count
-        of blocking device->host reads (``device_syncs``) and its filter
-        compilation counters (``filter_masks_compiled``,
-        ``filter_posting_entries``, ``filter_compile_s``) merged in at read
-        time (the planner owns them; nothing is hand-synced)."""
+        ``EngineStats`` with the planner's plan-cache counters, its counts
+        of blocking device->host reads (``device_syncs``) and of the arrays
+        they copied (``device_copies``) and its filter compilation counters
+        (``filter_masks_compiled``, ``filter_posting_entries``,
+        ``filter_compile_s``) merged in at read time (the planner owns
+        them; nothing is hand-synced)."""
         d = self._stats.as_dict()
         d.update(self.searcher.plan_cache_stats())
-        d["device_syncs"] = self.searcher.planner.syncs.total
+        syncs = self.searcher.planner.syncs
+        d["device_syncs"] = syncs.total
+        d["device_copies"] = syncs.copies
         d.update(self.searcher.planner.filter_compile_stats())
         return d
 
@@ -738,13 +742,10 @@ class ServingEngine:
         n = len(rows)
         pad = np.full((next_pow2(n),), rows[0], np.int64)  # pow2 gather shape
         pad[:n] = rows
-        with obs.tracer.span("gather-rows"):
-            gathered = _gather_rows(pool.state, pad)
-        core = self.searcher.planner.syncs.get("retire",
-                                               session.finalize(gathered))
+        core = session.retire(pool.state, pad)    # one dispatch, one copy
         core_rows = type(core)(*(f[:n] for f in core))
         qrows = np.stack([pool.requests[i].query for i in rows])
-        rounds = session.rounds(pool.state)[np.asarray(rows)]
+        rounds = core_rows.rounds
         with obs.tracer.span("complete", cat="plan"):
             pres = session.complete(qrows, core_rows)
         now = time.perf_counter()
@@ -813,9 +814,8 @@ class ServingEngine:
             if not fallback and self._watch is not None:
                 with obs.tracer.span("recompile-watch"):
                     self._watch.sample()
-                    # continuous pools gather-finalize at pow2 buckets up to
-                    # the slot count, so the budget widens to
-                    # max(batch, slots)
+                    # continuous pools retire at pow2 buckets up to the
+                    # slot count, so the budget widens to max(batch, slots)
                     width = max(self.batch_size, self.slots)
                     buckets = int(math.log2(next_pow2(width))) + 1
                     self._watch.check(

@@ -246,3 +246,18 @@ def test_convergence_records_match_one_round_path(tiny_index):
     for f in want:
         np.testing.assert_array_equal(got[f], want[f], err_msg=f)
     assert obs.convergence.labels == log.labels
+
+
+def test_served_retires_compile_nothing(tiny_index):
+    """A freshly opened continuous engine has warmed the packed retire at
+    every bucket it can pad to: serving twice its slots compiles no new
+    ``finalize_rows`` program."""
+    from repro.core.search import jit_cache_sizes
+
+    eng = ServingEngine(tiny_index, batch_size=8, continuous=True, slots=4)
+    before = jit_cache_sizes()["finalize_rows"]
+    for q in tiny_index.dataset.queries[:8]:
+        eng.submit(q)
+    done = eng.drain()
+    assert len(done) == 8 and eng.stats["retired"] == 8
+    assert jit_cache_sizes()["finalize_rows"] == before

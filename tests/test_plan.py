@@ -312,11 +312,13 @@ def test_engine_stats_derived_from_dataclass(tiny_index):
     assert d["plan_cache_misses"] >= 1
     assert d["plan_cache_hits"] >= 3
     assert d["device_syncs"] >= 1
+    # the batch fetch copies ids and dists: two arrays a sync
+    assert d["device_copies"] == 2 * d["device_syncs"]
     assert d["filter_masks_compiled"] == 0 == d["filter_compile_s"]
     assert set(d) == set(EngineStats().as_dict()) | {
         "plan_cache_hits", "plan_cache_misses",
-        "device_syncs", "filter_masks_compiled", "filter_posting_entries",
-        "filter_compile_s"}, "dict view drifted"
+        "device_syncs", "device_copies", "filter_masks_compiled",
+        "filter_posting_entries", "filter_compile_s"}, "dict view drifted"
 
 
 def test_validate_attribute_store_shared_helper(tiny_index, tiny_store):
@@ -662,3 +664,52 @@ def test_advance_retires_like_stepping(tiny_index, tiny_store, kind):
     np.testing.assert_array_equal(got_dists, np.asarray(ref.dists))
     np.testing.assert_array_equal(got_rounds, fix)
     assert dispatches == len(np.unique(fix)) < int(fix.max())
+
+
+# ---------------------------------------------------------------------------
+# Retire: gather, finalize and pack in one program, read in one copy
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def mid_flight(tiny_index, tiny_store):
+    """kind -> (RoundSession, 8-lane state after one advance dispatch:
+    some lanes quiesced, the rest mid-traversal)."""
+    states = {}
+
+    def of(kind):
+        if kind not in states:
+            sess, q = _session_of(tiny_index, tiny_store, kind)
+            state, _, _ = sess.advance(sess.init(q), sess.cfg.max_rounds)
+            states[kind] = sess, state
+        return states[kind]
+
+    return of
+
+
+@pytest.mark.parametrize("bucket", [1, 2, 4, 8])
+@pytest.mark.parametrize("kind", ["flat", "masked", "merged"])
+def test_retire_matches_gather_then_finalize(mid_flight, kind, bucket):
+    """``RoundSession.retire`` (one program, one packed copy) gives the ids,
+    the distances' bits and all six counters of the stepped reference,
+    ``finalize(_gather_rows(state, rows))``, on every row of a padded
+    power-of-two bucket, and the rounds ``rounds(state)`` reads."""
+    from repro.serve.engine import _gather_rows
+
+    sess, state = mid_flight(kind)
+    n = bucket // 2 + 1
+    rows = np.full((bucket,), 5, np.int64)        # padded as the engine does
+    rows[:n] = [5, 2, 7, 0, 3][:n]
+    ref = sess.finalize(_gather_rows(state, rows))
+    syncs = sess.planner.syncs
+    before = (syncs.total, syncs.copies)
+    got = sess.retire(state, rows)
+    assert (syncs.total, syncs.copies) == (before[0] + 1, before[1] + 1)
+    assert got._fields == ref._fields
+    for f in ref._fields:
+        want = np.asarray(getattr(ref, f))
+        have = getattr(got, f)
+        assert isinstance(have, np.ndarray) and have.dtype == want.dtype, f
+        if f == "dists":
+            want, have = want.view(np.int32), have.view(np.int32)
+        np.testing.assert_array_equal(have, want, err_msg=f)
+    np.testing.assert_array_equal(got.rounds, sess.rounds(state)[rows])

@@ -11,6 +11,8 @@ modules are imported would give pytest-xdist workers different tests.
 Everything built from the topology is built in fixtures or tests.
 """
 import dataclasses
+import hashlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -133,17 +135,22 @@ def test_graph_search_compiles_for_v5e(use_pallas, one_chip,
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
 
 
+def _state_shapes(sds, corpus):
+    from repro.core.search import init_search_state
+
+    state = jax.eval_shape(lambda q: init_search_state(corpus, q, CFG, "l2"),
+                           jax.ShapeDtypeStruct((Q, D), jnp.float32))
+    return jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), state)
+
+
 def test_graph_search_advance_compiles_for_v5e(one_chip, no_compile_cache):
     """The continuous scheduler's dispatch: rounds looped on the device,
     the slot pool's state in and out, one packed (Q + 1,) int32 read."""
-    from repro.core.search import graph_search_advance, init_search_state
+    from repro.core.search import graph_search_advance
 
     sds = _sds(one_chip)
     corpus = _corpus_shapes(sds)
-    state = jax.eval_shape(lambda q: init_search_state(corpus, q, CFG, "l2"),
-                           jax.ShapeDtypeStruct((Q, D), jnp.float32))
-    state = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), state)
-    compiled = graph_search_advance.lower(corpus, state,
+    compiled = graph_search_advance.lower(corpus, _state_shapes(sds, corpus),
                                           sds((), jnp.int32), CFG,
                                           "l2").compile()
     assert "while" in compiled.as_text()
@@ -151,6 +158,59 @@ def test_graph_search_advance_compiles_for_v5e(one_chip, no_compile_cache):
     assert out.shape == (Q + 1,) and out.dtype == jnp.int32
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+@pytest.mark.parametrize("rows", [1, Q])
+def test_finalize_rows_compiles_for_v5e(rows, one_chip, no_compile_cache):
+    """The continuous scheduler's retire: the chosen rows of a 64-lane pool
+    state gathered, reranked and packed into one (rows, 2k + 6) int32
+    array."""
+    from repro.core.search import finalize_rows
+
+    sds = _sds(one_chip)
+    corpus = _corpus_shapes(sds)
+    compiled = finalize_rows.lower(corpus, _state_shapes(sds, corpus),
+                                   sds((rows,), jnp.int32), CFG,
+                                   "l2").compile()
+    out = compiled.out_info
+    assert out.shape == (rows, 2 * K + 6) and out.dtype == jnp.int32
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+def _program_digest(compiled) -> str:
+    """sha256 of a compiled program's HLO text without its metadata: the
+    ``metadata={...}`` of each instruction and the tables of file names,
+    functions and stack frames they point into."""
+    tables = re.compile(r"(\d+ |(FileNames|FunctionNames|FileLocations|"
+                        r"StackFrames)$)")
+    text = "\n".join(line for line in compiled.as_text().splitlines()
+                     if not tables.match(line))
+    text = re.sub(r",? metadata=\{[^}]*\}", "", text)
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+# digests of the v5e programs of the batch kernel and the continuous
+# scheduler's advance before the retire became one program (jax 0.9.0):
+# adding ``finalize_rows`` must leave both programs as they were
+_SERVED_DIGESTS = {"graph_search": "8cbd1a6834231819",
+                   "graph_search_advance": "f9e2dbda8fdd7bfd"}
+
+
+@pytest.mark.parametrize("name", sorted(_SERVED_DIGESTS))
+def test_served_programs_unchanged_for_v5e(name, one_chip, no_compile_cache):
+    from repro.core.search import graph_search, graph_search_advance
+
+    sds = _sds(one_chip)
+    corpus = _corpus_shapes(sds)
+    if name == "graph_search":
+        lowered = graph_search.lower(corpus, sds((Q, D), jnp.float32), CFG,
+                                     "l2")
+    else:
+        lowered = graph_search_advance.lower(
+            corpus, _state_shapes(sds, corpus), sds((), jnp.int32), CFG,
+            "l2")
+    assert _program_digest(lowered.compile()) == _SERVED_DIGESTS[name]
 
 
 def test_distributed_search_compiles_on_4_chip_mesh(topo, no_compile_cache):

@@ -151,7 +151,7 @@ def test_continuous_engine_span_tree(tiny_index):
     assert ticks
     tick_names = ("refill", "admit", "init", "select-lanes", "quiet-lanes",
                   "round", "active-sync", "device-wait",
-                  "fetch", "retire", "gather-rows", "finalize", "complete",
+                  "fetch", "retire", "finalize", "complete",
                   "post-process", "recompile-watch")
     seen = set()
     for t in ticks:
@@ -160,10 +160,12 @@ def test_continuous_engine_span_tree(tiny_index):
     for name in tick_names:
         for e in _spans(tr, name):
             assert any(_inside(e, t) for t in ticks), name
+    # the retire is one program (``finalize``) and one read: no separate
+    # row gather
     for r in _spans(tr, "retire"):
         assert _children(tr, r, tick_names) >= {
-            "gather-rows", "finalize", "device-wait", "fetch", "complete",
-            "post-process"}
+            "finalize", "device-wait", "fetch", "complete", "post-process"}
+    assert not _spans(tr, "gather-rows")
     for a in _spans(tr, "admit"):
         assert _children(tr, a, tick_names) >= {"init", "quiet-lanes"}
     # the activity mask comes back with the advance dispatch (``round``):
@@ -203,6 +205,7 @@ def test_device_syncs_continuous_scheduler(tiny_index):
     eng = ServingEngine(tiny_index, batch_size=8, continuous=True, slots=4,
                         obs=obs)
     base = eng.stats["device_syncs"]
+    copies0 = eng.stats["device_copies"]
     obs.metrics.clear()
     obs.tracer.clear()
     d0, r0 = eng.stats["pool_dispatches"], eng.stats["pool_rounds"]
@@ -211,14 +214,15 @@ def test_device_syncs_continuous_scheduler(tiny_index):
     eng.drain()
     dispatches = eng.stats["pool_dispatches"] - d0
     retires = len(_spans(obs.tracer, "retire"))
-    # one active pull per advance dispatch, one core fetch and one rounds
-    # pull per dispatch that retires lanes; nothing else blocks
+    # one active pull per advance dispatch and one packed fetch (ids,
+    # dists, counters and rounds) per dispatch that retires lanes; nothing
+    # else blocks, and each read copies one array
     assert _syncs(obs) == {"site=active": float(dispatches),
-                           "site=retire": float(retires),
-                           "site=rounds": float(retires)}
+                           "site=retire": float(retires)}
     assert retires >= 2                        # 6 queries through 4 slots
     assert eng.stats["pool_rounds"] - r0 > dispatches   # rounds batched
-    assert eng.stats["device_syncs"] - base == dispatches + 2 * retires
+    assert eng.stats["device_syncs"] - base == dispatches + retires
+    assert eng.stats["device_copies"] - copies0 == dispatches + retires
     assert {k.split("=")[1] for k in _syncs(obs)} <= SYNC_SITES
 
 
